@@ -23,16 +23,19 @@ Claims:
 * **per-lane resume** — ``state_dict``/``load_state_dict`` round-trips the
   per-lane cursors and the resumed stream matches an unbroken run.
 
-A host with fewer than 4 jax devices re-executes itself in a subprocess
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (the flag must
-be set before jax initializes, same pattern as tests/test_dryrun_small.py).
+It needs at least 4 jax devices in this process and stops with an error
+on fewer; it never re-executes itself, because a child process cannot reach
+an accelerator its parent already holds.  On a CPU host, give it four
+virtual CPU devices before anything touches jax:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src python -m benchmarks.run --only sharded
+
+Every result names the platform it ran on; throughput on a CPU mesh is a
+host figure, not a device figure.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 from benchmarks.common import Result, Scale
@@ -225,7 +228,15 @@ def _run_local(scale: Scale) -> dict:
 
     from repro.launch.mesh import make_mesh
 
+    if jax.device_count() < MIN_DEVICES:
+        raise RuntimeError(
+            f"bench_sharded needs >= {MIN_DEVICES} devices, found "
+            f"{jax.device_count()} ({jax.devices()[0].platform}). On a CPU "
+            "host set JAX_PLATFORMS=cpu and XLA_FLAGS=--xla_force_host_"
+            f"platform_device_count={MIN_DEVICES} before starting python."
+        )
     mesh = make_mesh((jax.device_count(),), ("data",))
+    dev = jax.devices()[0]
     attempts = ATTEMPTS + 1 if scale.name == "full" else ATTEMPTS
     rows, best = [], 0.0
     lane_stats = {}
@@ -233,6 +244,7 @@ def _run_local(scale: Scale) -> dict:
         host_tput, sharded_tput, stats = _measure_pair(mesh)
         speedup = sharded_tput / max(host_tput, 1e-9)
         rows.append({
+            "platform": dev.platform,
             "attempt": i,
             "host_reshard_img_per_s": round(host_tput, 1),
             "sharded_img_per_s": round(sharded_tput, 1),
@@ -245,6 +257,8 @@ def _run_local(scale: Scale) -> dict:
             break
     return {
         "devices": jax.device_count(),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "rows": rows,
         "best_speedup": best,
         "lane_stats": lane_stats,
@@ -254,41 +268,12 @@ def _run_local(scale: Scale) -> dict:
     }
 
 
-def _run_in_subprocess(scale: Scale) -> dict:
-    """Re-exec with a forced 4-device CPU mesh (XLA_FLAGS must be set before
-    jax initializes, so the parent process can't just flip it)."""
-    env = dict(
-        os.environ,
-        XLA_FLAGS="--xla_force_host_platform_device_count="
-                  f"{MIN_DEVICES} " + os.environ.get("XLA_FLAGS", ""),
-        PYTHONPATH=os.pathsep.join(
-            p for p in ("src", os.environ.get("PYTHONPATH", "")) if p
-        ),
-    )
-    cmd = [sys.executable, "-m", "benchmarks.bench_sharded"]
-    if scale.name == "full":
-        cmd.append("--full")
-    out = subprocess.run(
-        cmd, capture_output=True, text=True, env=env, timeout=1800,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"bench_sharded subprocess failed:\n{out.stderr[-4000:]}"
-        )
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
 def run(scale: Scale) -> Result:
-    import jax
-
-    if jax.device_count() >= MIN_DEVICES:
-        rec = _run_local(scale)
-        note = f"in-process mesh of {rec['devices']} devices"
-    else:
-        rec = _run_in_subprocess(scale)
-        note = (f"subprocess CPU mesh of {rec['devices']} devices "
-                "(XLA_FLAGS fallback)")
+    rec = _run_local(scale)
+    note = (f"mesh of {rec['devices']} {rec['platform']} devices "
+            f"({rec['device_kind']})")
+    if rec["platform"] == "cpu":
+        note += ": virtual CPU devices, img/s are host figures, not device figures"
     result = Result(NAME, PAPER_REF, notes=note)
     result.rows = rec["rows"]
     best = rec["best_speedup"]
@@ -305,14 +290,3 @@ def run(scale: Scale) -> Result:
     ]
     return result
 
-
-def main() -> int:
-    from benchmarks.common import FULL, QUICK
-
-    scale = FULL if "--full" in sys.argv else QUICK
-    print(json.dumps(_run_local(scale)))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

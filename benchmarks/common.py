@@ -9,8 +9,12 @@ Every ``bench_*`` module maps to one paper table/figure and exposes::
 ``Result.rows`` is a list of flat dicts (one per measured cell) and
 ``Result.claims`` a list of (description, bool) paper-claim validations.
 ``run.py`` renders tables, writes ``reports/bench/<name>.json`` and prints a
-claim summary.  Benchmarks are CPU-only: remote storage is the calibrated
-:class:`SimulatedS3Store`; "scratch" is the in-memory/local path.
+claim summary.  Remote storage is the calibrated :class:`SimulatedS3Store`;
+"scratch" is the in-memory/local path.  These benchmarks run wherever jax
+runs and have so far only been run on the CPU: their timings are host
+figures (loader, storage, CPU stage), never device figures.  Counts they
+check (bytes copied or fetched, bit-identity) hold on any backend.  The only
+chip-side check is ``chip_smoke.py`` at the repo root.
 """
 from __future__ import annotations
 

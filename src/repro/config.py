@@ -119,7 +119,9 @@ class ModelConfig:
     param_dtype: str = "float32"
     remat: bool = True
     scan_layers: bool = True
-    # which attention implementation the model uses ("ref" | "pallas")
+    # which attention implementation the model uses: "ref" (jnp), "pallas"
+    # (the flash kernel, compiled for the TPU) or "pallas_interpret" (the
+    # same kernel in interpret mode, for CPU hosts)
     attention_impl: str = "ref"
 
     @property

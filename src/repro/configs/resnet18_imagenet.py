@@ -25,7 +25,7 @@ def smoke() -> ModelConfig:
         family="resnet",
         resnet_blocks=(1, 1),
         resnet_width=8,
-        num_classes=10,
+        num_classes=1000,  # the synthetic ImageNet's labels span all 1000
         image_size=32,
     )
 

@@ -531,7 +531,9 @@ def _cpu_proc_main(payload: bytes, conn, shm_spec=None) -> None:
     everything at an epoch takeover, ``slab_cap`` is the autotuner's live
     pressure knob.  Anything that can't pack falls back to the pickle
     ``done`` with the reason attached.  ``die`` is the test-only crash
-    injection hook (:meth:`_CPUProcessPool.inject_crash`)."""
+    injection hook (:meth:`_CPUProcessPool.inject_crash`).  A ``ready``
+    message, sent once the dataset is loaded, tells the parent it may send
+    tasks."""
     try:
         dataset = pickle.loads(payload)
     except BaseException as e:  # exotic: parent pre-validated pickling
@@ -552,6 +554,11 @@ def _cpu_proc_main(payload: bytes, conn, shm_spec=None) -> None:
             except OSError:
                 pass
             writer = None
+    try:
+        conn.send(("ready",))
+    except OSError:
+        conn.close()
+        return
     die_on_task: Optional[str] = None
     while True:
         try:
@@ -644,12 +651,16 @@ class _ProcWorker:
     the rebind — unsynchronized interleaved writes would corrupt the pickle
     stream."""
 
-    __slots__ = ("proc", "conn", "sids", "send_lock", "slab")
+    __slots__ = ("proc", "conn", "sids", "send_lock", "slab", "ready")
 
     def __init__(self, proc, conn, slab=None) -> None:
         self.proc = proc
         self.conn = conn
         self.sids: List[int] = []  # at most PROC_PREFILL_DEPTH entries
+        # set by the child's "ready" message: a task queued on a worker that
+        # is still starting waits out its start-up (hundreds of ms) while
+        # started workers decode everything after it
+        self.ready = False
         self.send_lock = threading.Lock()
         self.slab: Optional[shm_mod.ParentSlab] = slab  # shm transport only
 
@@ -802,11 +813,14 @@ class _CPUProcessPool:
     def inject_crash(self, mode: str = "now", worker: int = 0) -> None:
         """TEST HOOK: make worker ``worker`` die — ``"now"`` immediately,
         ``"mid_slab_write"`` on its next task with a slot claimed and
-        half-written (exercising crash-safe slot reclamation)."""
+        half-written (exercising crash-safe slot reclamation).  ``worker``
+        counts the workers that take tasks; one still starting may get none
+        before the epoch ends."""
         with self._lock:
             if not self.workers:
                 raise RuntimeError("no workers to crash")
-            w = self.workers[worker % len(self.workers)]
+            live = [w for w in self.workers if w.ready] or self.workers
+            w = live[worker % len(live)]
         w.send(("die", mode))
 
     def close(self) -> None:
@@ -838,8 +852,8 @@ class _ProcCPUStage:
     fetch->decode queue under the :class:`AdjustableSemaphore` gate (a gate
     permit is held from claim to final resolution, so resizes drain exactly
     like the thread stage), assigns up to :data:`PROC_PREFILL_DEPTH` tasks
-    per worker over its pipe (one executing, one queued — the spare hides
-    the parent round trip between samples), multiplexes completions with
+    per started worker over its pipe (one executing, one queued — the spare
+    hides the parent round trip between samples), multiplexes completions with
     ``multiprocessing.connection.wait``, and records the shipped
     decode/augment spans under the worker's pid lane.
     Crash handling: a dead worker's in-flight sample is requeued ahead of
@@ -873,6 +887,8 @@ class _ProcCPUStage:
         self.gate = AdjustableSemaphore(PROC_PREFILL_DEPTH * self._width)
         self.active = True
         self.requeued = 0  # samples retried after a worker crash
+        self._boot_deaths = 0  # workers that died before their "ready"
+        self._failed = False
         self._inflight: Dict[int, _Sample] = {}
         self._attempts: Dict[int, int] = {}
         self._pending: Deque[int] = deque()  # crash-requeued sids, FIFO
@@ -882,6 +898,7 @@ class _ProcCPUStage:
         self.shm_samples = 0
         self.pipe_samples = 0
         self.fallbacks: Dict[str, int] = {}
+        self.spilled = 0  # shm samples copied out of a nearly full slab
         self.bytes_copied = 0
         pool.attach(self, payload)
         if pool.shm_spec is not None:
@@ -914,7 +931,7 @@ class _ProcCPUStage:
             self._flush_frees()
             self._dispatch()
             workers = list(self.pool.workers)
-            busy = [w.conn for w in workers if w.sids]
+            busy = [w.conn for w in workers if w.sids or not w.ready]
             if busy:
                 for conn in _mp_wait(busy, timeout=0.05):
                     w = next(
@@ -949,7 +966,7 @@ class _ProcCPUStage:
             # emptiest eligible worker first: fill every idle worker before
             # granting anyone its prefill spare
             candidates = [x for x in list(self.pool.workers)
-                          if len(x.sids) < PROC_PREFILL_DEPTH
+                          if x.ready and len(x.sids) < PROC_PREFILL_DEPTH
                           and x.proc.is_alive()]
             if not candidates:
                 return
@@ -1009,6 +1026,17 @@ class _ProcCPUStage:
             w.conn.close()
             self.pool.remove(w)
             self.pool.respawns += 1
+            if not w.ready:
+                self._boot_deaths += 1
+        if (self._boot_deaths >= PROC_TASK_ATTEMPTS and not self._failed
+                and not any(x.ready for x in self.pool.workers)):
+            # workers die before they can take a task (e.g. the dataset does
+            # not unpickle in the child): no sample would ever be retried,
+            # so fail the epoch with the child's diagnostic
+            self._failed = True
+            self.done_q.put((None, _Failure(RuntimeError(
+                f"{self._boot_deaths} cpu workers died while starting; last "
+                f"worker diagnostic: {self.pool.last_error}"))))
 
     def _retry_or_fail(self, sid: int, exc: BaseException) -> None:
         s = self._inflight.get(sid)
@@ -1026,6 +1054,9 @@ class _ProcCPUStage:
 
     def _resolve(self, w: _ProcWorker, msg: Tuple) -> None:
         tag = msg[0]
+        if tag == "ready":
+            w.ready = True
+            return
         if tag == "crash":
             # the worker is about to exit; reap accounts for it and retries
             # its task (if any).  Keep the child's diagnostic — it is the
@@ -1701,6 +1732,28 @@ class _PipelineIter:
                 self.io.submit(_Sample(task.batch_id, pos, index))
 
     # -- assembly ------------------------------------------------------------
+    def _spill(self, s: _Sample, item: Any) -> Any:
+        """shm transport: a sample that the next emit will not collate gives
+        its slot back once its worker's slab is nearly full.  Its values are
+        copied out (one counted copy), so the slots go to the samples the
+        consumer waits on instead of those falling back to the pickle pipe.
+        Without this, one slow fetch lets decode run ahead by as many
+        samples as complete behind it, each holding a slot."""
+        if not isinstance(item, shm_mod.ShmItem):
+            return item
+        if self.strict:
+            head = s.batch_id == self._next_bid
+        else:
+            head = self._gid_of_bid.get(s.batch_id) == self._cur_group
+        if head or item.slab_in_use() + PROC_PREFILL_DEPTH < self._slab_cap:
+            return item
+        out = item.detach()
+        nbytes = shm_mod.item_nbytes(out)
+        self._proc_cpu.spilled += 1
+        self._proc_cpu.bytes_copied += nbytes
+        self.tracer.count(BYTES_COPIED, nbytes)
+        return out
+
     def _absorb(self, s: _Sample, item: Any) -> None:
         self._completed_samples += 1
         if self._assembler is not None:
@@ -1708,7 +1761,9 @@ class _PipelineIter:
             # collate/h2d thread; the composed batch comes back through
             # done_q as a _Composed token, landing in _ready below
             self._assembler.add(s.batch_id, s.pos, item)
-        elif self.strict:
+            return
+        item = self._spill(s, item)
+        if self.strict:
             slots = self._slots[s.batch_id]
             slots[s.pos] = item
             self._remaining[s.batch_id] -= 1
@@ -1897,6 +1952,7 @@ class _PipelineIter:
                     round(sum(stage.fallbacks.values()) / samples, 4)
                     if samples else 0.0
                 ),
+                "spilled": stage.spilled,
                 "bytes_copied": stage.bytes_copied,
             }
             if pool.shm_spec is not None:

@@ -103,6 +103,18 @@ class ShmItem(dict):
             self._released = True
             self._slab.queue_free(self._slot, self._gen)
 
+    def slab_in_use(self) -> int:
+        """Slots of this sample's slab the parent holds right now."""
+        return self._slab.in_use
+
+    def detach(self) -> Dict[str, Any]:
+        """Copy the values out of the slab into a plain dict and release the
+        slot now, for a sample that must wait for its batch while its
+        worker runs short of slots."""
+        out = {k: np.array(v) for k, v in self.items()}
+        self.release()
+        return out
+
     def __reduce__(self):
         # crossing a process boundary would detach the views from the slab's
         # lifetime; materialise a plain dict instead

@@ -33,6 +33,8 @@ def make_ingest_fn(
     out_dtype: Any = jnp.float32,
     impl: str = "auto",
     interpret: bool = False,
+    mesh: Optional[Any] = None,
+    axis: str = "data",
 ) -> Any:
     """Build the on-device ingest epilogue for ``DevicePrefetchRing``.
 
@@ -40,7 +42,13 @@ def make_ingest_fn(
     :func:`repro.data.augment.to_tensor_normalize`).  ``impl`` picks the
     kernel: ``"pallas"`` (the fused fma kernel), ``"ref"`` (pure jnp, what
     XLA fuses on CPU/GPU), or ``"auto"`` (pallas on TPU, ref elsewhere —
-    interpret-mode pallas would serialize the grid on CPU).
+    interpret-mode pallas would serialize the grid on CPU).  The choice is
+    recorded on the returned callable as ``.impl`` so a caller can say
+    which kernel ran.
+
+    ``mesh`` is for batches sharded along ``axis`` of a device mesh (sharded
+    delivery): a Pallas kernel cannot be partitioned by the compiler, so it
+    then runs under ``shard_map``, each device on its own rows.
 
     The returned callable is safe to apply to any batch dict: it only
     rewrites ``key`` when it finds a uint8 NHWC array, so host-epilogue
@@ -54,25 +62,35 @@ def make_ingest_fn(
 
         mean = IMAGENET_MEAN if mean is None else mean
         std = IMAGENET_STD if std is None else std
-    mean = jnp.asarray(np.asarray(mean, dtype=np.float32))
-    std = jnp.asarray(np.asarray(std, dtype=np.float32))
+    mean = np.asarray(mean, dtype=np.float32)
+    std = np.asarray(std, dtype=np.float32)
     use_pallas = impl == "pallas" or (
         impl == "auto" and jax.default_backend() == "tpu"
     )
+
+    def norm(img):
+        if use_pallas:
+            return ingest_norm_batched(
+                img, jnp.asarray(mean), jnp.asarray(std), out_dtype=out_dtype,
+                interpret=interpret,
+            )
+        return ingest_norm_ref(img, jnp.asarray(mean), jnp.asarray(std),
+                               out_dtype=out_dtype)
+
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        norm = jax.shard_map(norm, mesh=mesh, in_specs=P(axis),
+                             out_specs=P(axis), check_vma=False)
 
     @jax.jit
     def ingest(batch: Dict[str, Any]) -> Dict[str, Any]:
         img = batch.get(key) if hasattr(batch, "get") else None
         if img is None or img.dtype != jnp.uint8 or img.ndim != 4:
             return dict(batch) if isinstance(batch, dict) else batch
-        if use_pallas:
-            out = ingest_norm_batched(
-                img, mean, std, out_dtype=out_dtype, interpret=interpret
-            )
-        else:
-            out = ingest_norm_ref(img, mean, std, out_dtype=out_dtype)
         new = dict(batch)
-        new[key] = out
+        new[key] = norm(img)
         return new
 
+    ingest.impl = "pallas" if use_pallas else "ref"
     return ingest

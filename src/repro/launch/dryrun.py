@@ -42,7 +42,7 @@ from repro.config import (
 )
 from repro.configs import ASSIGNED
 from repro.launch import specs as S
-from repro.launch.hlo_cost import analyze_hlo, cost_analysis_dict, cpu_bf16_upcast_bytes
+from repro.launch.hlo_cost import analyze_hlo, cpu_bf16_upcast_bytes
 from repro.launch.mesh import V5E_HBM_BYTES, make_production_mesh
 from repro.launch.roofline import Roofline, parse_collectives
 from repro.models import encdec, transformer
@@ -153,7 +153,7 @@ def lower_cell(
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     # cost_analysis() counts while bodies ONCE; with scan-over-layers +

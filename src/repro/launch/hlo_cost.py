@@ -33,14 +33,6 @@ from typing import Dict, List, Optional, Tuple
 from repro.launch.roofline import _DTYPE_BYTES, _group_size
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts, newer ones a flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COMP_START = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*(?:\([^)]*\))?.*\{\s*$")
 _CALL_ATTR = re.compile(r"(?:calls|to_apply)=%?([\w\.\-]+)")

@@ -16,16 +16,11 @@ V5E_HBM_BW = 819e9  # bytes/s per chip
 V5E_ICI_BW = 50e9  # bytes/s per link
 V5E_HBM_BYTES = 16 * 1024**3  # 16 GiB per chip
 
-# jax.sharding.AxisType landed after 0.4.x; Auto is the pre-AxisType default
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _make(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -12,11 +12,17 @@ the paper's Table-3 columns (throughput + accelerator busy stats) at the end.
 ImageNet; every other arch trains on packed token sequences streamed through
 the same loader.  ``--smoke`` (default) uses the reduced config so the run
 fits a CPU host; ``--full`` lowers the real config (use on real hardware).
+
+:func:`run` is the same driver for callers in the same process (it takes an
+argv list and extra trainer callbacks, and hands back the loader and the
+trainer); ``chip_smoke.py`` drives the paper's path on a TPU through it.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.random as jr
@@ -38,6 +44,7 @@ from repro.core.utilization import accelerator_stats
 from repro.data.dataset import ImageDataset, TokenDataset, build_token_store
 from repro.data.imagenet_synth import build_synthetic_imagenet
 from repro.data.store import build_store
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.train.checkpoint import CheckpointManager
 from repro.train.steps import (
     init_resnet_train_state,
@@ -45,7 +52,12 @@ from repro.train.steps import (
     make_resnet_train_step,
     make_train_step,
 )
-from repro.train.trainer import CheckpointCallback, LoggingCallback, Trainer
+from repro.train.trainer import (
+    Callback,
+    CheckpointCallback,
+    LoggingCallback,
+    Trainer,
+)
 
 
 def build_dataset(cfg, args, tracer):
@@ -72,7 +84,49 @@ def build_dataset(cfg, args, tracer):
     return TokenDataset(store, args.items, seq, tracer=tracer)
 
 
-def main() -> int:
+def build_loader(cfg, args, tracer, mesh=None):
+    """The loader ``args`` describe, over :func:`build_dataset`; ``mesh``
+    switches to sharded delivery along ``args.delivery_axis``."""
+    delivery = (DeliverySpec.host() if mesh is None
+                else DeliverySpec.sharded(mesh, axis=args.delivery_axis))
+    return make_loader(
+        LoaderConfig(
+            impl=args.loader,
+            batch_size=args.batch_size,
+            num_workers=args.workers,
+            num_fetch_workers=args.fetchers,
+            hedge_requests=args.hedge,
+            pipeline=PipelineConfig(
+                enabled=args.pipeline or mesh is not None,
+                reorder=args.reorder,
+                reorder_window=args.reorder_window,
+                io_workers=args.io_workers,
+                cpu_workers=args.cpu_workers,
+                cpu_executor=args.cpu_executor,
+                transport=args.transport,
+                staging_buffers=args.staging_buffers,
+            ),
+            delivery=delivery,
+            autotune=AutotuneConfig(
+                enabled=args.autotune or args.thread_budget > 0,
+                thread_budget=args.thread_budget,
+            ),
+            seed=args.seed,
+        ),
+        build_dataset(cfg, args, tracer),
+        tracer=tracer,
+    )
+
+
+@dataclass
+class TrainRun:
+    """What :func:`run` built and measured."""
+
+    loader: Any
+    trainer: Trainer
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -146,13 +200,20 @@ def main() -> int:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def run(argv: Optional[Sequence[str]] = None,
+        callbacks: Sequence[Callback] = ()) -> TrainRun:
+    """Build the store -> loader -> step stack from ``argv`` and train.
+    ``callbacks`` join the trainer's own (logging, checkpointing)."""
+    args = parse_args(argv)
+    cache_dir = enable_compilation_cache()
+    dev = jax.devices()[0]
+    print(f"device: platform={dev.platform} kind={dev.device_kind} "
+          f"count={jax.device_count()} compile_cache={cache_dir}", flush=True)
 
     cfg = get_arch(args.arch, smoke=args.smoke)
-    atcfg = AutotuneConfig(
-        enabled=args.autotune or args.thread_budget > 0,
-        thread_budget=args.thread_budget,
-    )
     tcfg = TrainConfig(
         optimizer=args.optimizer,
         learning_rate=args.lr,
@@ -161,41 +222,14 @@ def main() -> int:
         total_steps=args.steps,
     )
     tracer = Tracer()
-    dataset = build_dataset(cfg, args, tracer)
-    delivery = DeliverySpec.host()
+    mesh = None
     if args.delivery == "sharded":
         # one lane per local device along the data axis; multi-host runs
         # pass a jax.distributed mesh here instead
         from repro.launch.mesh import make_mesh
 
-        delivery = DeliverySpec.sharded(
-            make_mesh((jax.device_count(),), (args.delivery_axis,)),
-            axis=args.delivery_axis,
-        )
-    loader = make_loader(
-        LoaderConfig(
-            impl=args.loader,
-            batch_size=args.batch_size,
-            num_workers=args.workers,
-            num_fetch_workers=args.fetchers,
-            hedge_requests=args.hedge,
-            pipeline=PipelineConfig(
-                enabled=args.pipeline or args.delivery == "sharded",
-                reorder=args.reorder,
-                reorder_window=args.reorder_window,
-                io_workers=args.io_workers,
-                cpu_workers=args.cpu_workers,
-                cpu_executor=args.cpu_executor,
-                transport=args.transport,
-                staging_buffers=args.staging_buffers,
-            ),
-            delivery=delivery,
-            autotune=atcfg,
-            seed=args.seed,
-        ),
-        dataset,
-        tracer=tracer,
-    )
+        mesh = make_mesh((jax.device_count(),), (args.delivery_axis,))
+    loader = build_loader(cfg, args, tracer, mesh)
 
     key = jr.PRNGKey(args.seed)
     if cfg.family == "resnet":
@@ -204,12 +238,21 @@ def main() -> int:
     else:
         state = init_train_state(cfg, tcfg, key)
         step_fn = make_train_step(cfg, tcfg)
+    if mesh is not None:
+        # data parallel over the delivery mesh: every device holds the whole
+        # train state, the batch arrives sharded along the data axis
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        state = jax.device_put(state, NamedSharding(mesh, PartitionSpec()))
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(state["params"]))
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M "
           f"loader={args.loader} store={args.store}")
 
-    callbacks = [LoggingCallback(log_every_n_steps=args.log_every,
-                                 sink=lambda s: print("  " + s, flush=True))]
+    callbacks: List[Callback] = [
+        LoggingCallback(log_every_n_steps=args.log_every,
+                        sink=lambda s: print("  " + s, flush=True)),
+        *callbacks,
+    ]
     manager = None
     if args.ckpt_dir:
         manager = CheckpointManager(args.ckpt_dir, keep=3)
@@ -222,7 +265,9 @@ def main() -> int:
             raise SystemExit("--device-ingest requires an image (resnet) arch")
         from repro.kernels.ingest_norm.ops import make_ingest_fn
 
-        ingest_fn = make_ingest_fn()
+        ingest_fn = make_ingest_fn(mesh=mesh, axis=args.delivery_axis)
+        print(f"ingest: {ingest_fn.impl} (make_ingest_fn impl=auto on "
+              f"{jax.default_backend()})", flush=True)
     trainer = Trainer(step_fn, state, callbacks=callbacks, tracer=tracer,
                       ingest_fn=ingest_fn)
 
@@ -250,13 +295,20 @@ def main() -> int:
         f"items/s={items / result.wall_s:.1f} "
         f"loss={result.last_metrics.get('loss', float('nan')):.4f}"
     )
+    # a host-clock proxy over run_training_batch spans, not a device trace
     print(
-        f"accelerator: util_zero={util.util_zero_pct:.1f}% "
+        f"host-span proxy (run_training_batch spans): "
+        f"util_zero={util.util_zero_pct:.1f}% "
         f"util_pos_avg={util.util_pos_avg:.1f}% busy={100 * util.busy_fraction:.1f}%"
     )
     stages = loader.stage_stats()
     if stages is not None:
         print(f"pipeline stages: {stages}")
+    return TrainRun(loader, trainer)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    run(argv)
     return 0
 
 

@@ -169,10 +169,11 @@ def _sdpa(q, k, v, *, causal: bool, q_offset, kv_len: Optional[jnp.ndarray] = No
     """Dispatch: dense tile for short q, flash-style q-chunked for long q
     (static shape decision — resolved at trace time).  ``impl="pallas"``
     routes the no-cache causal self-attention path through the Pallas flash
-    kernel (TPU target; interpret=True on CPU hosts)."""
+    kernel (compiled for the TPU); ``impl="pallas_interpret"`` runs the same
+    kernel in interpret mode (CPU hosts, tests)."""
     S = q.shape[1]
     if (
-        impl == "pallas"
+        impl in ("pallas", "pallas_interpret")
         and kv_len is None
         and causal
         and S == k.shape[1]  # full self-attention (train / whole prefill)
@@ -183,8 +184,8 @@ def _sdpa(q, k, v, *, causal: bool, q_offset, kv_len: Optional[jnp.ndarray] = No
         qf = q.reshape(B, S, Hkv * G, D).transpose(0, 2, 1, 3)  # (B,Hq,S,D)
         kf = k.transpose(0, 2, 1, 3)  # (B,Hkv,T,D)
         vf = v.transpose(0, 2, 1, 3)
-        interp = jax.default_backend() != "tpu"
-        out = flash_attention(qf, kf, vf, causal=True, interpret=interp)
+        out = flash_attention(qf, kf, vf, causal=True,
+                              interpret=impl == "pallas_interpret")
         return out.transpose(0, 2, 1, 3).reshape(B, S, Hkv, G, D)
     if S >= CHUNKED_SDPA_THRESHOLD and S % 1024 == 0:
         return _sdpa_chunked(q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len)

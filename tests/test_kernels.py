@@ -161,8 +161,9 @@ def test_ingest_norm_matches_ref(shape, out_dtype):
 
 
 def test_pallas_attention_wired_into_model():
-    """cfg.attention_impl='pallas' routes train-time self-attention through
-    the Pallas flash kernel (interpret on CPU) with matching loss."""
+    """cfg.attention_impl='pallas_interpret' routes train-time
+    self-attention through the Pallas flash kernel (in interpret mode, as
+    this runs on CPU) with matching loss."""
     import dataclasses
 
     import jax
@@ -179,5 +180,5 @@ def test_pallas_attention_wired_into_model():
     }
     l_ref, _ = T.forward_train(params, batch, cfg)
     l_pal, _ = T.forward_train(
-        params, batch, dataclasses.replace(cfg, attention_impl="pallas"))
+        params, batch, dataclasses.replace(cfg, attention_impl="pallas_interpret"))
     assert abs(float(l_ref) - float(l_pal)) < 5e-3

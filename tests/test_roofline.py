@@ -130,9 +130,7 @@ def test_flops_match_6nd_closed_form():
     assert 1.0 <= ratio <= 2.5, ratio
     # cost_analysis undercounts this scanned program (sanity that the fix
     # matters): while bodies once => less than the closed form.
-    from repro.launch.hlo_cost import cost_analysis_dict
-
-    assert float(cost_analysis_dict(compiled).get("flops", 0)) < model_flops_per_dev
+    assert float(compiled.cost_analysis().get("flops", 0)) < model_flops_per_dev
 
 
 def test_roofline_terms():

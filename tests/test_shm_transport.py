@@ -101,6 +101,28 @@ class TestSlab:
             writer.close()
             parent.close()
 
+    def test_detach_copies_out_and_frees_the_slot(self):
+        parent, writer = self._pair()
+        try:
+            item = {"image": np.arange(12, dtype=np.float32), "label": np.int32(3)}
+            handle, _ = writer.try_pack(item)
+            view = parent.view_item(handle)
+            assert view.slab_in_use() == 1
+            out = view.detach()
+            assert type(out) is dict and parent.in_use == 0
+            assert parent.drain_freed() == [(handle[0], handle[1])]
+            # the copy owns its memory: rewriting the slot leaves it intact
+            writer.free_slots([(handle[0], handle[1])])
+            writer.try_pack({"image": np.zeros(12, np.float32),
+                             "label": np.int32(0)})
+            for k in item:
+                np.testing.assert_array_equal(out[k], item[k])
+            view.release()  # already released: a no-op
+            assert parent.in_use == 0
+        finally:
+            writer.close()
+            parent.close()
+
     def test_stale_generation_free_ignored(self):
         parent, writer = self._pair()
         try:
@@ -265,6 +287,32 @@ def test_crash_mid_slab_write_retries_and_stream_survives(dataset):
     pool = getattr(dl, "_cpu_pool", None)
     if pool is not None:
         pool.close()
+
+
+class _NoDatasetInWorkers(ImageDataset):
+    """Pickles in the parent, refuses to unpickle in a spawned worker."""
+
+    def __setstate__(self, state):
+        import multiprocessing
+
+        if multiprocessing.parent_process() is not None:
+            raise RuntimeError("dataset refused in a worker")
+        super().__setstate__(state)
+
+
+def test_workers_dying_at_start_fail_the_epoch_with_their_diagnostic(dataset):
+    ds = _NoDatasetInWorkers(dataset.store, N_ITEMS, out_size=24)
+    dl = ConcurrentDataLoader(ds, pipe_cfg("shm"))
+    try:
+        # no worker ever starts, so no sample is sent to one: the pump must
+        # fail the epoch itself instead of respawning until the timeout
+        with pytest.raises(RuntimeError, match="died while starting.*refused"):
+            list(dl)
+        assert dl.stage_stats()["cpu_pool"]["crashes"] >= 3
+    finally:
+        pool = getattr(dl, "_cpu_pool", None)
+        if pool is not None:
+            pool.close()
 
 
 def test_resume_cursor_equivalence_across_transports(dataset):
