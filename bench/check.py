@@ -1,0 +1,118 @@
+"""The comparison that decides ``correct``.
+
+What the timed path produced in its first steps (the batches the step
+received, the losses it reported, its optimizer state after one step and its
+parameters after three) is set against the plain references in
+``bench/reference``. Each number is compared with a limit of its own, kept
+per cell in ``bench/limits/<cell>.json``:
+
+* ``batch_mismatch`` -- rows (image or label) of the first three batches that
+  differ from the reference loader's. The step's input is the normalised
+  float32 batch; its uint8 pixels are recovered exactly by inverting the
+  normalisation and rounding. Exact: limit 0.
+* ``ingest_err`` -- the largest absolute gap between the ingest kernel's
+  output and a plain normalisation of the reference loader's pixels.
+* ``loss_gap`` -- the largest relative gap of a step's loss.
+* ``grad_gap`` -- the first gradient, as the optimizer got it (clipped),
+  recovered from the first moment after one step (``mu / (1 - beta1)``): the
+  worst leaf's gap between the program's norm and the reference's, over the
+  larger of that leaf's reference norm and the median leaf's.
+* ``update_gap`` -- the same for the parameters' change over three steps,
+  leaving out leaves whose reference gradient is under a thousandth of the
+  median leaf's (they move by round-off alone).
+* ``grad_gap_median``, ``update_gap_median`` -- the median leaf's gap of
+  each: steady from seed to seed where the worst leaf swings, and the
+  numbers that tell a step run in a lower precision from the program's.
+
+A cell's limits file names the numbers it compares.
+"""
+from __future__ import annotations
+
+from typing import Dict, List, Sequence
+
+import numpy as np
+
+from bench.reference.model import MEAN, STD
+
+CHECKED_STEPS = 3
+
+
+def recover_u8(images_nchw: np.ndarray) -> np.ndarray:
+    """Normalised float (B, 3, H, W) -> the uint8 (B, H, W, 3) it came from."""
+    x = images_nchw.astype(np.float64).transpose(0, 2, 3, 1)
+    px = (x * np.asarray(STD) + np.asarray(MEAN)) * 255.0
+    return np.clip(np.rint(px), 0, 255).astype(np.uint8)
+
+
+def batch_mismatch(prog: Sequence, ref: Sequence) -> int:
+    """Rows that differ, over (images NCHW float, labels) against
+    (images NHWC uint8, labels)."""
+    if len(prog) != len(ref):
+        return sum(len(rlab) for _, rlab in ref)
+    bad = 0
+    for (img, lab), (rimg, rlab) in zip(prog, ref):
+        if img.shape[0] != rimg.shape[0]:
+            bad += len(rlab)
+            continue
+        u8 = recover_u8(img)
+        rows = (u8.reshape(len(u8), -1) != rimg.reshape(len(rimg), -1)).any(1)
+        bad += int((rows | (np.asarray(lab) != np.asarray(rlab))).sum())
+    return bad
+
+
+def max_abs(a: np.ndarray, b: np.ndarray) -> float:
+    if a.shape != b.shape:
+        return float("inf")
+    return float(np.abs(a.astype(np.float64) - b).max())
+
+
+def leaf_gaps(prog: List[np.ndarray], ref: List[np.ndarray],
+              keep: Sequence[bool] = ()) -> np.ndarray:
+    """Per leaf: |norm(prog) - norm(ref)| / max(norm(ref), median norm(ref));
+    leaves not kept read NaN."""
+    if len(prog) != len(ref):
+        return np.full(len(ref), np.inf)
+    pn = np.array([np.linalg.norm(p.astype(np.float64)) for p in prog])
+    rn = np.array([np.linalg.norm(r.astype(np.float64)) for r in ref])
+    keep = np.asarray(keep, bool) if len(keep) else np.ones(len(rn), bool)
+    med = float(np.median(rn[keep]))
+    return np.where(keep, np.abs(pn - rn) / np.maximum(rn, med), np.nan)
+
+
+def gap_leaves(prog: Dict, ref: Dict, beta1: float):
+    """Per-leaf gaps of the first gradient and of the change over three
+    steps (NaN where a leaf is left out)."""
+    g_prog = [m / (1.0 - beta1) for m in prog["mu1"]]
+    rnorm = np.array([np.linalg.norm(g.astype(np.float64)) for g in ref["g1"]])
+    moving = rnorm >= 1e-3 * np.median(rnorm)
+    d_prog = [a.astype(np.float64) - b for a, b in zip(prog["p3"], prog["p0"])]
+    d_ref = [a.astype(np.float64) - b for a, b in zip(ref["p3"], ref["p0"])]
+    return leaf_gaps(g_prog, ref["g1"]), leaf_gaps(d_prog, d_ref, moving)
+
+
+def compare(prog: Dict, ref: Dict, beta1: float) -> Dict[str, float]:
+    """The numbers compared. ``prog``/``ref`` hold ``batches`` (as above),
+    ``losses``, ``p0`` and ``p3`` (parameter leaves before step 1 and after
+    step 3) and, for the program, ``mu1`` (first-moment leaves after step 1),
+    for the reference ``g1`` (its clipped first gradient), ``ingest`` (the
+    program's normalised batches) and ``normalized`` (the reference's)."""
+    grad, update = gap_leaves(prog, ref, beta1)
+    lp, lr = np.asarray(prog["losses"], np.float64), np.asarray(ref["losses"], np.float64)
+    out = {
+        "batch_mismatch": float(batch_mismatch(prog["batches"], ref["batches"])),
+        "ingest_err": max(max_abs(a, b) for a, b in zip(prog["ingest"], ref["normalized"])),
+        "loss_gap": float(np.max(np.abs(lp - lr) / np.abs(lr))) if len(lp) == len(lr)
+        else float("inf"),
+        "grad_gap": float(np.nanmax(grad)),
+        "update_gap": float(np.nanmax(update)),
+        "grad_gap_median": float(np.nanmedian(grad)),
+        "update_gap_median": float(np.nanmedian(update)),
+    }
+    return out
+
+
+def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:
+    """Correct when every number is finite and within its limit, and every
+    limit has its number."""
+    return all(k in numbers and np.isfinite(numbers[k]) and numbers[k] <= lim
+               for k, lim in limits.items())
