@@ -30,9 +30,10 @@ path into an explicit stage graph::
   bit-identical), ``"window"`` fills each aligned group of
   ``reorder_window`` batch slots with whichever of the group's samples
   finish first, so a straggler only delays the *last* batch of its group.
-* **Per-stage observability** — every sample records ``stage_fetch`` /
-  ``stage_decode`` / ``stage_augment`` spans and every batch a
-  ``stage_collate`` span; inter-stage queues track occupancy
+* **Per-stage observability** — every sample records ``io_admit`` (waiting
+  for an IO slot), ``stage_fetch``, ``io_handoff`` (its slot held while the
+  fetch->decode queue takes it), ``stage_decode`` / ``stage_augment`` spans
+  and every batch a ``stage_collate`` span; inter-stage queues track occupancy
   (:meth:`_PipelineIter.stage_stats`), which is how ``bench_pipeline``
   proves decode/IO overlap.
 * **Per-stage tuning** — io workers, cpu workers, the outstanding sample
@@ -67,7 +68,9 @@ from repro.core.fetcher import (
 from repro.core.sampler import BatchIndices
 from repro.core.tracing import (
     BYTES_COPIED,
-    SHUFFLE_ENTROPY,
+    IO_ADMIT,
+    IO_HANDOFF,
+    NULL_TRACER,
     STAGE_AUGMENT,
     STAGE_COLLATE,
     STAGE_DECODE,
@@ -78,13 +81,14 @@ from repro.core.tracing import (
 class _Sample:
     """One flattened unit of work flowing through the stage graph."""
 
-    __slots__ = ("batch_id", "pos", "index", "raw")
+    __slots__ = ("batch_id", "pos", "index", "raw", "t_submit")
 
     def __init__(self, batch_id: int, pos: int, index: int) -> None:
         self.batch_id = batch_id
         self.pos = pos
         self.index = index
         self.raw: Any = None
+        self.t_submit = 0.0  # monotonic time of _IOStage.submit
 
 
 class _Failure:
@@ -183,6 +187,11 @@ class _IOStage:
     fetch->decode queue: when decode backs up, IO concurrency drains to zero
     instead of buffering unboundedly.
 
+    With a live tracer each sample records ``io_admit`` (submit to permit)
+    and ``io_handoff`` (end of the GET to the hand-off's acceptance, the
+    permit held but not fetching); with ``NULL_TRACER`` admission costs one
+    ``time.monotonic`` per sample.
+
     Hedging (both modes, reusing :class:`HedgeTracker`): the assembler
     loop calls :meth:`hedge_scan`; any in-flight fetch older than the p95
     deadline gets one ungated duplicate — on the pool's headroom threads
@@ -211,6 +220,7 @@ class _IOStage:
         self.done_q = done_q
         self.stop = stop
         self.tracer = tracer
+        self._traced = tracer is not NULL_TRACER
         self.hedge = hedge
         self.hard_cap = max(width, hard_cap)
         self.gate = AdjustableSemaphore(width)
@@ -238,6 +248,7 @@ class _IOStage:
 
     # -- admission -----------------------------------------------------------
     def submit(self, sample: _Sample) -> None:
+        sample.t_submit = time.monotonic()
         with self._lock:
             self._pending.append(sample)
         self._kick()
@@ -248,6 +259,9 @@ class _IOStage:
                 if not self._pending or not self.gate.acquire(timeout=0):
                     return
                 s = self._pending.popleft()
+            if self._traced:
+                self.tracer.record(IO_ADMIT, s.t_submit, time.monotonic(),
+                                   index=s.index, batch_id=s.batch_id)
             if self._loop is not None:
                 asyncio.run_coroutine_threadsafe(self._afetch(s), self._loop)
             else:
@@ -260,18 +274,28 @@ class _IOStage:
         return w
 
     # -- completion (first response wins when hedged) ------------------------
-    def _complete(self, s: _Sample, raw: Any) -> bool:
-        """Route a finished fetch downstream; returns False when the other
-        copy of a hedged fetch already claimed the sample."""
+    def _complete(self, s: _Sample, raw: Any, t_got: float, hedge: bool = False) -> bool:
+        """Route a finished fetch (its GET ended at ``t_got``) downstream;
+        returns False when the other copy of a hedged fetch already claimed
+        the sample."""
         with self._lock:
             if self._inflight.pop(id(s), None) is None:
                 return False
+        self._hand_off(s, raw, t_got, hedge)
+        return True
+
+    def _hand_off(self, s: _Sample, raw: Any, t_got: float, hedge: bool) -> None:
+        """Pass a fetched sample downstream, blocking while the fetch->decode
+        queue is full, and record the ``io_handoff`` span."""
         if self.split:
             s.raw = raw
             self.decode_q.put(s)
         else:
             self.done_q.put((s, raw))  # raw IS the finished item (monolithic)
-        return True
+        if self._traced:
+            args = {"hedge": True} if hedge else {}
+            self.tracer.record(IO_HANDOFF, t_got, time.monotonic(),
+                               index=s.index, batch_id=s.batch_id, **args)
 
     def _fail(self, s: _Sample, exc: BaseException) -> None:
         with self._lock:
@@ -296,7 +320,7 @@ class _IOStage:
                                batch_id=s.batch_id)
             if self.hedge is not None:
                 self.hedge.observe(t1 - t0)
-            self._complete(s, raw)
+            self._complete(s, raw, t1)
         except BaseException as e:
             self._fail(s, e)
         finally:
@@ -308,9 +332,10 @@ class _IOStage:
         t0 = time.monotonic()
         try:
             raw = self._fetch_value(s)
-            self.tracer.record(STAGE_FETCH, t0, time.monotonic(),
+            t1 = time.monotonic()
+            self.tracer.record(STAGE_FETCH, t0, t1,
                                index=s.index, batch_id=s.batch_id, hedge=True)
-            if self._complete(s, raw) and self.hedge is not None:
+            if self._complete(s, raw, t1, hedge=True) and self.hedge is not None:
                 self.hedge.hedges_won += 1
         except BaseException:
             pass  # the original is still in flight; let it decide the outcome
@@ -339,7 +364,8 @@ class _IOStage:
                 self._pool.submit(self._run_hedge, s)
 
     # -- asyncio fetch -------------------------------------------------------
-    async def _acomplete(self, s: _Sample, raw: Any) -> bool:
+    async def _acomplete(self, s: _Sample, raw: Any, t_got: float,
+                         hedge: bool = False) -> bool:
         """Async mirror of :meth:`_complete`: same first-response-wins pop,
         but the (possibly blocking) decode-queue hand-off runs in an executor
         so other in-flight GETs keep progressing on the event loop."""
@@ -347,12 +373,11 @@ class _IOStage:
             if self._inflight.pop(id(s), None) is None:
                 return False  # the other copy of a hedged fetch already won
         if self.split:
-            s.raw = raw
             await asyncio.get_running_loop().run_in_executor(
-                None, self.decode_q.put, s
+                None, self._hand_off, s, raw, t_got, hedge
             )
         else:
-            self.done_q.put((s, raw))
+            self._hand_off(s, raw, t_got, hedge)
         return True
 
     async def _afetch(self, s: _Sample) -> None:
@@ -367,7 +392,7 @@ class _IOStage:
                                index=s.index, batch_id=s.batch_id)
             if self.hedge is not None:
                 self.hedge.observe(t1 - t0)
-            await self._acomplete(s, raw)
+            await self._acomplete(s, raw, t1)
         except BaseException as e:
             self._fail(s, e)
         finally:
@@ -380,9 +405,10 @@ class _IOStage:
         try:
             fetch = self.dataset.aget_raw if self.split else self.dataset.aget_item
             raw = await aretry_transient(fetch, s.index)
-            self.tracer.record(STAGE_FETCH, t0, time.monotonic(),
+            t1 = time.monotonic()
+            self.tracer.record(STAGE_FETCH, t0, t1,
                                index=s.index, batch_id=s.batch_id, hedge=True)
-            if await self._acomplete(s, raw) and self.hedge is not None:
+            if await self._acomplete(s, raw, t1, hedge=True) and self.hedge is not None:
                 self.hedge.hedges_won += 1
         except BaseException:
             pass  # the original is still in flight; let it decide the outcome
@@ -1155,27 +1181,22 @@ class _ShuffleMeter:
       batches.  Uniform shuffling spreads each stratum evenly (≈1); epochs
       where a stratum's samples bunch into a few batches score low.
 
-    One :data:`SHUFFLE_ENTROPY` tracer span is recorded per measurement
-    window, tagging both values — the audit trail the autotuner's entropy
-    floor (``AutotuneConfig.min_shuffle_entropy``) is judged against."""
+    :meth:`snapshot` is ``stage_stats()["shuffle"]``, what the autotuner's
+    entropy floor (``AutotuneConfig.min_shuffle_entropy``) is judged
+    against."""
 
-    def __init__(self, dataset_len: int, tracer, *, buckets: int = 16,
+    def __init__(self, dataset_len: int, *, buckets: int = 16,
                  window_batches: int = 32) -> None:
         self.n = max(1, int(dataset_len))
         self.buckets = max(2, min(buckets, self.n))
         self.window_batches = max(2, window_batches)
-        self.tracer = tracer
         self._hists: Deque[np.ndarray] = deque(maxlen=self.window_batches)
         self._within: Deque[float] = deque(maxlen=self.window_batches)
         self.batches = 0
-        self._win_t0: Optional[float] = None
 
     def note_batch(self, indices) -> None:
         if indices is None or len(indices) == 0:
             return
-        now = time.monotonic()
-        if self._win_t0 is None:
-            self._win_t0 = now
         idx = np.asarray(indices, dtype=np.int64)
         strata = np.minimum(idx * self.buckets // self.n, self.buckets - 1)
         hist = np.bincount(strata, minlength=self.buckets).astype(np.float64)
@@ -1186,14 +1207,6 @@ class _ShuffleMeter:
         self._within.append(within)
         self._hists.append(hist)
         self.batches += 1
-        if self.batches % self.window_batches == 0:
-            snap = self.snapshot()
-            self.tracer.record(
-                SHUFFLE_ENTROPY, self._win_t0, now,
-                within=snap["within_batch"], across=snap["across_batch"],
-                batches=self.batches,
-            )
-            self._win_t0 = None
 
     def snapshot(self) -> Dict[str, Any]:
         if not self._within:
@@ -1447,7 +1460,7 @@ class _PipelineIter:
         # shuffle-quality estimator over the delivered index stream (the
         # evidence behind stage_stats()["shuffle"] and the autotuner's
         # reorder-window entropy floor)
-        self._shuffle = _ShuffleMeter(loader.sampler.dataset_len, self.tracer)
+        self._shuffle = _ShuffleMeter(loader.sampler.dataset_len)
         # strict/sharded batch composition equals the sampler's dispatch —
         # remember it so delivery can be scored without re-deriving indices
         self._batch_indices: Dict[int, Tuple[int, ...]] = {}

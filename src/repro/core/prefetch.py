@@ -15,14 +15,23 @@ Zero-copy extensions (PR 7): batches collated into pooled staging buffers
 transfer lands, and ``ingest_fn`` runs a jitted on-device epilogue (the
 fused ``kernels/ingest_norm`` cast+normalize) right after the put — raw
 uint8 crosses the bus, the f32 batch is born on device.
+
+With a live tracer, ``batch_to_device`` is also a
+``jax.profiler.TraceAnnotation`` (:meth:`Tracer.annotated_span`): the ring's
+transfers show on a profiler trace beside the device's operations.
+
+A host array of rank above 2 crosses to a single device as rows
+(:func:`_put_rows`) and takes its shape back there.
 """
 from __future__ import annotations
 
+import math
 import queue
 import threading
 from typing import Any, Iterator, Optional
 
 import jax
+import numpy as np
 
 from repro.core.fetcher import AdjustableSemaphore
 from repro.core.tracing import BATCH_TO_DEVICE, NULL_TRACER, Tracer
@@ -35,6 +44,20 @@ class _End:
 class _Err:
     def __init__(self, exc: BaseException) -> None:
         self.exc = exc
+
+
+def _put_rows(x: Any) -> Any:
+    """``jax.device_put`` of one leaf onto the default device.  A host array
+    of rank above 2 crosses as ``(rows, -1)`` and is reshaped on the device:
+    the TPU runtime lays such an array out on the host before the copy, and
+    a small minor dimension makes that slow.  For 256 HWC uint8 images of
+    224 px (38.5 MB) on a TPU v5e: 42 ms as (256, 224, 224, 3), 8 ms as
+    rows; and 2.1 s while ``jax.profiler`` traces with its host tracer on,
+    which leaves the rows' 8 ms as it is."""
+    if isinstance(x, np.ndarray) and x.ndim > 2:
+        rows = x.reshape(x.shape[0], math.prod(x.shape[1:]))
+        return jax.device_put(rows).reshape(x.shape)
+    return jax.device_put(x)
 
 
 class DevicePrefetchRing:
@@ -89,7 +112,7 @@ class DevicePrefetchRing:
         # transfer a plain-dict view so device_put sees the arrays; `batch`
         # keeps the staged identity for the release below
         host = dict(batch) if isinstance(batch, dict) and type(batch) is not dict else batch
-        with self.tracer.span(BATCH_TO_DEVICE):
+        with self.tracer.annotated_span(BATCH_TO_DEVICE):
             if callable(self.sharding):
                 dev = jax.tree.map(
                     lambda x: jax.device_put(x, self.sharding(x)), host
@@ -97,7 +120,7 @@ class DevicePrefetchRing:
             elif self.sharding is not None:
                 dev = jax.tree.map(lambda x: jax.device_put(x, self.sharding), host)
             else:
-                dev = jax.tree.map(jax.device_put, host)
+                dev = jax.tree.map(_put_rows, host)
             # block until the transfer lands so the span is honest
             jax.tree.map(
                 lambda x: x.block_until_ready() if hasattr(x, "block_until_ready") else x,

@@ -5,13 +5,18 @@ Records named spans (``get_batch``, ``get_item``, ``batch_to_device``,
 the log-entry instrumentation in the paper.  Exports Chrome ``trace_event``
 JSON so the Fig. 2 timeline can be inspected in Perfetto, and computes the
 Table-3 style busy/idle statistics (see :mod:`repro.core.utilization`).
+
+Spans are taken on ``time.monotonic``.  The few per step on the trainer and
+device-ring threads (:meth:`Tracer.annotated_span`) are also entered as
+``jax.profiler.TraceAnnotation`` events, so a profiler trace shows them on
+its own clock beside the device's operations.
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Sequence
 
@@ -38,20 +43,19 @@ STAGE_COLLATE = "stage_collate"
 LANE_COLLATE = "lane_collate"
 LANE_H2D = "lane_h2d"
 STAGE_COMPOSE = "stage_compose"
-# serving read path (repro.serve.readpath): one span per ReadPath.get,
-# tagged with tenant, serving source (memory | disk | coalesced | fetch),
-# and whether a hedge fired — the trace-replay harness computes its
-# p50/p99/p999 claims over this lane
-SERVE_GET = "serve_get"
 # monotonic counter (not a span lane): host bytes physically copied on a
 # sample's way from decode to device — the zero-copy transport's figure of
 # merit (bench_shm divides it by samples drained to get bytes/sample)
 BYTES_COPIED = "bytes_copied"
-# shuffle-quality lane (repro.core.pipeline): one span per entropy
-# measurement window, tagged with the normalized within-batch and
-# across-batch entropies — the evidence bench_columnar's entropy-floor
-# claim (AutotuneConfig.min_shuffle_entropy) is audited against
-SHUFFLE_ENTROPY = "shuffle_entropy"
+# trainer lane (repro.train.trainer): one span per ``next(ring)`` on the
+# step loop, tagged step=n — the time training is blocked on the device ring
+LOADER_WAIT = "loader_wait"
+# IO-stage admission lanes (repro.core.pipeline), one span each per sample,
+# tagged index= and batch_id=: IO_ADMIT from submit to the IO gate permit
+# (queueing for an IO slot), IO_HANDOFF from the end of the GET to the
+# fetch->decode queue accepting the sample (the slot held, not fetching)
+IO_ADMIT = "io_admit"
+IO_HANDOFF = "io_handoff"
 
 
 @dataclass
@@ -121,6 +125,18 @@ class Tracer:
             if extra:
                 args.update(extra)
             self.record(name, t0, t1, **args)
+
+    @contextmanager
+    def annotated_span(self, name: str, **args: Any) -> Iterator[Dict[str, Any]]:
+        """:meth:`span` that is also a ``jax.profiler.TraceAnnotation`` of the
+        same name, so it lands on the profiler's clock too (the annotation
+        opens before and closes after the span's endpoints).  For the few
+        spans per step of the trainer and the device ring; jax is imported
+        here, never by the CPU stage's worker processes."""
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(name), self.span(name, **args) as extra:
+            yield extra
 
     def spans(self, name: Optional[str] = None) -> List[Span]:
         with self._lock:
@@ -201,6 +217,9 @@ class _NullTracer(Tracer):
 
     def count(self, name: str, n: float = 1) -> None:
         pass
+
+    def annotated_span(self, name: str, **args: Any):
+        return nullcontext({})
 
 
 NULL_TRACER = _NullTracer()

@@ -54,6 +54,9 @@ def make_ingest_fn(
     rewrites ``key`` when it finds a uint8 NHWC array, so host-epilogue
     batches and non-image pipelines pass through unchanged (the dtype check
     happens at trace time — no device-side branching).
+
+    Its program is ``jit_ingest`` and the kernel sits under the ``ingest``
+    scope: the names by which a profiler trace's reader finds it.
     """
     if impl not in ("auto", "pallas", "ref"):
         raise ValueError(f"impl must be auto|pallas|ref, got {impl!r}")
@@ -89,7 +92,8 @@ def make_ingest_fn(
         if img is None or img.dtype != jnp.uint8 or img.ndim != 4:
             return dict(batch) if isinstance(batch, dict) else batch
         new = dict(batch)
-        new[key] = norm(img)
+        with jax.named_scope("ingest"):
+            new[key] = norm(img)
         return new
 
     ingest.impl = "pallas" if use_pallas else "ref"

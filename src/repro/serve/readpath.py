@@ -25,9 +25,9 @@ is *tail latency*.  Three mechanisms, each independently configurable through
 With ``ServeSpec.autotune.enabled`` (``objective="latency"``) the path runs
 an :class:`repro.core.autotune.AutotuneController` fed per-request latencies:
 the hedge delay, coalesce window, and (tiered-cache stacks) the cache knobs
-hill-climb against the p99 target.  Every request records a ``serve_get``
-tracing span; ``benchmarks/bench_serve.py`` replays Zipf/diurnal/flash-crowd
-traces over this class for the p50/p99/p999 claims.
+hill-climb against the p99 target.  Per-tenant latencies and sources are
+in :meth:`ReadPath.stats`; ``benchmarks/bench_serve.py`` replays
+Zipf/diurnal/flash-crowd traces over this class for the p50/p99/p999 claims.
 """
 from __future__ import annotations
 
@@ -44,7 +44,7 @@ from repro.core.autotune import (
     build_cache_knobs,
     build_serve_knobs,
 )
-from repro.core.tracing import NULL_TRACER, SERVE_GET, Tracer
+from repro.core.tracing import NULL_TRACER, Tracer
 
 HEDGE_MODES = ("off", "fixed", "slo")
 
@@ -398,10 +398,6 @@ class ReadPath:
         res = self._serve(key, ten, timeout)
         end = self._clock()
         res.latency_s = end - t0
-        self.tracer.record(
-            SERVE_GET, t0, end, tenant=ten.name, source=res.source,
-            hedged=res.hedged, nbytes=len(res.data),
-        )
         with ten.lock:
             ten.requests += 1
             ten.by_source[res.source] += 1

@@ -133,6 +133,10 @@ def init_resnet_train_state(cfg: ModelConfig, tcfg: TrainConfig, key) -> Dict[st
 
 
 def make_resnet_train_step(cfg: ModelConfig, tcfg: TrainConfig) -> Callable:
+    """The ResNet step.  Jitted, its program is ``jit_train_step`` and its
+    operations sit under the ``train_step`` scope: the names by which a
+    profiler trace's reader finds the step (``tests/test_loader_spans.py``
+    pins them)."""
     opt = make_optimizer(tcfg)
 
     def train_step(state, batch):
@@ -140,14 +144,16 @@ def make_resnet_train_step(cfg: ModelConfig, tcfg: TrainConfig) -> Callable:
             loss, (new_bn, acc) = resnet.resnet_loss(p, state["bn"], batch, cfg, train=True)
             return loss, (new_bn, acc)
 
-        (loss, (new_bn, acc)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state["params"]
-        )
-        gnorm = global_norm(grads)
-        new_params, new_opt = opt.update(grads, state["opt"], state["params"], state["step"])
-        new_state = dict(
-            state, params=new_params, bn=new_bn, opt=new_opt, step=state["step"] + 1
-        )
+        with jax.named_scope("train_step"):
+            (loss, (new_bn, acc)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                state["params"]
+            )
+            gnorm = global_norm(grads)
+            new_params, new_opt = opt.update(grads, state["opt"], state["params"],
+                                             state["step"])
+            new_state = dict(
+                state, params=new_params, bn=new_bn, opt=new_opt, step=state["step"] + 1
+            )
         return new_state, {"loss": loss, "accuracy": acc, "grad_norm": gnorm}
 
     return train_step

@@ -8,18 +8,21 @@ callbacks with configurable frequency/cost).
 
 Both paths share the jitted step, the ConcurrentDataLoader and the device
 prefetch ring, and record the paper's span lanes so Table-3 style stats come
-out of the same tracer.
+out of the same tracer: ``loader_wait`` around each ``next(ring)`` and
+``run_training_batch`` around each step, both also on the profiler's clock
+(:meth:`~repro.core.tracing.Tracer.annotated_span`).
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
 
 import jax
 
 from repro.core.prefetch import DevicePrefetchRing
 from repro.core.tracing import (
+    LOADER_WAIT,
     NULL_TRACER,
     RUN_TRAINING_BATCH,
     Tracer,
@@ -127,6 +130,22 @@ def _make_ring(loader, depth: int, tracer, ingest_fn=None) -> DevicePrefetchRing
     return ring
 
 
+_END = object()
+
+
+def _waited(ring: DevicePrefetchRing, tracer: Tracer, step: int) -> Iterator[Any]:
+    """Iterate the device ring, each ``next`` a ``loader_wait`` span tagged
+    with the step that gets the batch (the last is the wait for the epoch's
+    end)."""
+    while True:
+        with tracer.annotated_span(LOADER_WAIT, step=step):
+            batch = next(ring, _END)
+        if batch is _END:
+            return
+        yield batch
+        step += 1
+
+
 def _release_coordination(loader) -> None:
     """End-of-fit courtesy for multi-host runs: hand back any held up-probe
     lease so co-located hosts don't wait out the crash TTL before climbing."""
@@ -184,9 +203,9 @@ class Trainer:
             self._hook("on_epoch_start", epoch)
             ring = _make_ring(loader, self.device_prefetch, self.tracer,
                               ingest_fn=self.ingest_fn)
-            for i, batch in enumerate(ring):
+            for i, batch in enumerate(_waited(ring, self.tracer, self.global_step)):
                 self._hook("on_train_batch_start", batch, i)
-                with self.tracer.span(RUN_TRAINING_BATCH, step=self.global_step):
+                with self.tracer.annotated_span(RUN_TRAINING_BATCH, step=self.global_step):
                     self.state, m = self.train_step(self.state, batch)
                     m = jax.tree.map(float, jax.device_get(m))
                 self.global_step += 1
@@ -235,8 +254,8 @@ def raw_train_loop(
         if hasattr(loader, "set_epoch") and epoch:
             loader.set_epoch(epoch)
         ring = _make_ring(loader, device_prefetch, tracer, ingest_fn=ingest_fn)
-        for batch in ring:
-            with tracer.span(RUN_TRAINING_BATCH, step=steps):
+        for batch in _waited(ring, tracer, steps):
+            with tracer.annotated_span(RUN_TRAINING_BATCH, step=steps):
                 state, m = step_fn(state, batch)
                 metrics = jax.tree.map(float, jax.device_get(m))
             history.append(metrics)

@@ -18,7 +18,6 @@ from repro.core.autotune import AutotuneController, build_reorder_knob
 from repro.core.loader import ConcurrentDataLoader
 from repro.core.pipeline import _ShuffleMeter
 from repro.core.sampler import ShardedBatchSampler
-from repro.core.tracing import NULL_TRACER
 from repro.data.columnar import (
     ColumnarError,
     ColumnarImageDataset,
@@ -429,7 +428,7 @@ def test_filtered_resume_cursor(col_base):
 
 def test_shuffle_meter_sequential_vs_shuffled():
     n, bs = 256, 16
-    seq = _ShuffleMeter(n, NULL_TRACER)
+    seq = _ShuffleMeter(n)
     for k in range(n // bs):
         seq.note_batch(tuple(range(k * bs, (k + 1) * bs)))
     s = seq.snapshot()
@@ -440,7 +439,7 @@ def test_shuffle_meter_sequential_vs_shuffled():
 
     rng = np.random.default_rng(0)
     perm = rng.permutation(n)
-    shuf = _ShuffleMeter(n, NULL_TRACER)
+    shuf = _ShuffleMeter(n)
     for k in range(n // bs):
         shuf.note_batch(tuple(int(v) for v in perm[k * bs:(k + 1) * bs]))
     t = shuf.snapshot()
@@ -449,7 +448,7 @@ def test_shuffle_meter_sequential_vs_shuffled():
 
 
 def test_shuffle_meter_empty():
-    m = _ShuffleMeter(64, NULL_TRACER)
+    m = _ShuffleMeter(64)
     assert m.snapshot() == {"within_batch": None, "across_batch": None,
                             "batches": 0}
 

@@ -398,6 +398,24 @@ def test_ring_applies_ingest_and_releases_staged_batches(dataset):
         pool.close()
 
 
+@pytest.mark.parametrize("rows", [3, 0])
+def test_ring_transfers_high_rank_arrays_exactly(rows):
+    """Rank > 2 leaves cross as rows and take their shape back on device."""
+    from repro.core.prefetch import DevicePrefetchRing
+
+    rng = np.random.default_rng(rows)
+    batch = {"image": rng.integers(0, 255, (rows, 5, 4, 3), dtype=np.uint8),
+             "mask": rng.random((rows, 2, 3)).astype(np.float32),
+             "tokens": rng.integers(0, 9, (rows, 7)).astype(np.int32),
+             "label": np.arange(rows, dtype=np.int32)}
+    ring = DevicePrefetchRing(iter([batch]), depth=1)
+    (got,) = list(ring)
+    ring.close()
+    for k, v in batch.items():
+        assert got[k].shape == v.shape and got[k].dtype == v.dtype
+        np.testing.assert_array_equal(np.asarray(got[k]), v)
+
+
 # --------------------------------------------------------------------------
 # sharded delivery × shm transport (4-device subprocess)
 # --------------------------------------------------------------------------
