@@ -497,10 +497,12 @@ class PipelineConfig:
     #              delays the last batch of its group, not its own batch
     reorder: str = "strict"
     reorder_window: int = 4
-    # stage sizing.  0 = derive: io_workers defaults to
+    # stage sizing.  io_workers 0 = derive: the IO gate starts at
     # num_workers * num_fetch_workers (the legacy loader's total fetch
-    # thread count, so pipeline-vs-legacy comparisons run at equal
-    # concurrency); cpu_workers defaults to 4.
+    # thread count) and, unless the autotuner owns it, widens from there
+    # while observed GET latency sets the pace (never below that seed, at
+    # most the outstanding sample window); >0 pins the width.  cpu_workers
+    # 0 = 4.
     io_workers: int = 0
     cpu_workers: int = 0
     # CPU (decode+augment) stage executor:

@@ -53,7 +53,6 @@ import threading
 import time
 import weakref
 from collections import deque
-from concurrent.futures import ThreadPoolExecutor
 from multiprocessing.connection import wait as _mp_wait
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -70,6 +69,8 @@ from repro.core.tracing import (
     BYTES_COPIED,
     IO_ADMIT,
     IO_HANDOFF,
+    IO_NARROWED,
+    IO_WIDENED,
     NULL_TRACER,
     STAGE_AUGMENT,
     STAGE_COLLATE,
@@ -128,6 +129,10 @@ class _BoundedQ:
         self._occ_sum = 0
         self._occ_n = 0
         self._occ_max = 0
+        # puts, and puts that found the queue full (the taking stage was
+        # behind): the IO width controller's backpressure signal
+        self.puts = 0
+        self.full_puts = 0
 
     @property
     def depth(self) -> int:
@@ -138,19 +143,23 @@ class _BoundedQ:
         self._cap.set_limit(d)
         return d
 
-    def _note(self) -> None:
+    def _note(self, put: bool = False) -> None:
         size = self._q.qsize()
         with self._lock:
             self._occ_sum += size
             self._occ_n += 1
             self._occ_max = max(self._occ_max, size)
+            self.puts += put
 
     def put(self, item: Any) -> bool:
-        while not self._cap.acquire(timeout=0.1):
-            if self._stop.is_set():
-                return False
+        if not self._cap.acquire(timeout=0):
+            with self._lock:
+                self.full_puts += 1
+            while not self._cap.acquire(timeout=0.1):
+                if self._stop.is_set():
+                    return False
         self._q.put(item)
-        self._note()
+        self._note(put=True)
         return True
 
     def get(self, timeout: float = 0.1) -> Any:
@@ -192,9 +201,14 @@ class _IOStage:
     permit held but not fetching); with ``NULL_TRACER`` admission costs one
     ``time.monotonic`` per sample.
 
+    Threaded mode runs the GETs on ``pipe-io`` threads that take admitted
+    samples from a work queue; they are started as the gate's limit first
+    reaches each width, up to ``hard_cap``, plus two, so the thread count
+    follows the widest the gate has been, not the hard cap.
+
     Hedging (both modes, reusing :class:`HedgeTracker`): the assembler
     loop calls :meth:`hedge_scan`; any in-flight fetch older than the p95
-    deadline gets one ungated duplicate — on the pool's headroom threads
+    deadline gets one ungated duplicate — on the two headroom threads
     (threaded) or as an extra coroutine on the event loop (asyncio) — and
     the first completion wins via the shared ``_inflight`` pop.
     """
@@ -231,20 +245,37 @@ class _IOStage:
         # pops the entry owns the sample; the loser finds it gone and drops
         # its result.
         self._inflight: Dict[int, Tuple[_Sample, float]] = {}
+        # primary GET seconds since the width controller last read them
+        # (None: no controller, nothing recorded)
+        self.get_s: Optional[List[float]] = None
         if mode == "asyncio":
             self._loop = asyncio.new_event_loop()
             self._thread = threading.Thread(
                 target=self._loop.run_forever, name="pipe-io-loop", daemon=True
             )
             self._thread.start()
-            self._pool = None
         else:
             self._loop = None
-            # +2 headroom threads so hedge duplicates can run while every
-            # gated slot is busy with stragglers
-            self._pool = ThreadPoolExecutor(
-                max_workers=self.hard_cap + 2, thread_name_prefix="pipe-io"
-            )
+            # (fn, sample) items, None ends a thread
+            self._work: "queue.SimpleQueue" = queue.SimpleQueue()
+            self._threads: List[threading.Thread] = []
+            self._ensure_threads()
+
+    def _ensure_threads(self) -> None:
+        """Start IO threads up to the gate's limit (at most the hard cap),
+        plus two headroom threads so hedge duplicates can run while every
+        gated slot is busy with stragglers."""
+        with self._lock:
+            while len(self._threads) < min(self.gate.limit, self.hard_cap) + 2:
+                t = threading.Thread(target=self._io_thread, daemon=True,
+                                     name=f"pipe-io-{len(self._threads)}")
+                self._threads.append(t)
+                t.start()
+
+    def _io_thread(self) -> None:
+        while (item := self._work.get()) is not None:
+            fn, s = item
+            fn(s)
 
     # -- admission -----------------------------------------------------------
     def submit(self, sample: _Sample) -> None:
@@ -256,7 +287,8 @@ class _IOStage:
     def _kick(self) -> None:
         while True:
             with self._lock:
-                if not self._pending or not self.gate.acquire(timeout=0):
+                if (self.stop.is_set() or not self._pending
+                        or not self.gate.acquire(timeout=0)):
                     return
                 s = self._pending.popleft()
             if self._traced:
@@ -265,13 +297,33 @@ class _IOStage:
             if self._loop is not None:
                 asyncio.run_coroutine_threadsafe(self._afetch(s), self._loop)
             else:
-                self._pool.submit(self._run_fetch, s)
+                self._work.put((self._run_fetch, s))
 
     def resize(self, width: int) -> int:
         w = max(1, min(int(width), self.hard_cap))
         self.gate.set_limit(w)
+        if self._loop is None:
+            self._ensure_threads()
         self._kick()  # a raised limit admits parked samples immediately
         return w
+
+    def parked(self) -> int:
+        """Samples submitted but still waiting for a gate permit."""
+        return len(self._pending)
+
+    def oldest_get_t0(self) -> Optional[float]:
+        """Start of the oldest primary GET in flight, or None (a hedged
+        straggler's entry is re-armed into the future, so it does not count)."""
+        with self._lock:
+            return min((t for _, t in self._inflight.values()), default=None)
+
+    def _observe(self, dt: float) -> None:
+        """Feed a primary GET's duration to hedging and the width controller."""
+        if self.hedge is not None:
+            self.hedge.observe(dt)
+        gets = self.get_s
+        if gets is not None:
+            gets.append(dt)
 
     # -- completion (first response wins when hedged) ------------------------
     def _complete(self, s: _Sample, raw: Any, t_got: float, hedge: bool = False) -> bool:
@@ -310,6 +362,9 @@ class _IOStage:
         return retry_transient(self.dataset.__getitem__, s.index)
 
     def _run_fetch(self, s: _Sample) -> None:
+        if self.stop.is_set():
+            self.gate.release()  # queued before shutdown: not fetched
+            return
         t0 = time.monotonic()
         with self._lock:
             self._inflight[id(s)] = (s, t0)
@@ -318,8 +373,7 @@ class _IOStage:
             t1 = time.monotonic()
             self.tracer.record(STAGE_FETCH, t0, t1, index=s.index,
                                batch_id=s.batch_id)
-            if self.hedge is not None:
-                self.hedge.observe(t1 - t0)
+            self._observe(t1 - t0)
             self._complete(s, raw, t1)
         except BaseException as e:
             self._fail(s, e)
@@ -361,7 +415,7 @@ class _IOStage:
                 # ungated like the threaded pool's headroom duplicates
                 asyncio.run_coroutine_threadsafe(self._ahedge(s), self._loop)
             else:
-                self._pool.submit(self._run_hedge, s)
+                self._work.put((self._run_hedge, s))
 
     # -- asyncio fetch -------------------------------------------------------
     async def _acomplete(self, s: _Sample, raw: Any, t_got: float,
@@ -390,8 +444,7 @@ class _IOStage:
             t1 = time.monotonic()
             self.tracer.record(STAGE_FETCH, t0, t1,
                                index=s.index, batch_id=s.batch_id)
-            if self.hedge is not None:
-                self.hedge.observe(t1 - t0)
+            self._observe(t1 - t0)
             await self._acomplete(s, raw, t1)
         except BaseException as e:
             self._fail(s, e)
@@ -414,8 +467,21 @@ class _IOStage:
             pass  # the original is still in flight; let it decide the outcome
 
     def close(self) -> None:
-        if self._pool is not None:
-            self._pool.shutdown(wait=False, cancel_futures=True)
+        if self._loop is None:
+            # the stop event is set: nothing more is admitted, samples
+            # already queued give their permits back unfetched, and each
+            # thread ends after its current GET (a blocked hand-off gives
+            # up within its 0.1 s poll); join them, a widened gate may have
+            # started hundreds
+            with self._lock:
+                threads = list(self._threads)
+            for _ in threads:
+                self._work.put(None)
+            deadline = time.monotonic() + 5.0
+            me = threading.current_thread()
+            for t in threads:
+                if t is not me:
+                    t.join(timeout=max(0.0, deadline - time.monotonic()))
         if self._loop is not None:
             def _cancel_and_stop() -> None:
                 # cancel in-flight fetch/hedge coroutines before stopping so
@@ -428,6 +494,147 @@ class _IOStage:
             self._thread.join(timeout=5)
             if not self._loop.is_running():
                 self._loop.close()
+
+
+# The derived IO width's control rule (_IOWidth).  Each constant is one
+# decision of the rule:
+# - a widening step of 1.25x takes a 64-slot seed to 256 in seven steps,
+#   while one step past the store's connection pool overshoots it by at
+#   most a quarter;
+IO_WIDEN = 1.25
+# - a short-window median GET this many times the lowest one seen means the
+#   store queues requests: more GETs in flight add wait, not throughput.
+#   A store that queues in order doubles its median GET at twice its
+#   connection pool; below 2x lie what shared bandwidth adds (an S3-like
+#   store's median GET grows 1.23x from 64 to 256 in flight), the low bias
+#   of the lowest of many noisy medians (~12%) and the interpreter lock's
+#   share of a GET on a busy host;
+IO_LATENCY_TOL = 2.0
+# - a GET still in flight this many times the window's 90th percentile GET
+#   is starved: a connection pool whose waiters are not served in order (a
+#   thread that just released a connection takes it again) leaves the
+#   median untouched while the GETs beyond its size wait for seconds.  Six
+#   times the p90 of lognormal GETs (sigma 0.5) is 11.4x their median, which
+#   a healthy GET exceeds once in 1.8 million.  The p90, not the longest:
+#   starved GETs that do finish would raise the longest;
+IO_STALL = 6.0
+# - a window lasts two median GETs, so that most GETs of the window after a
+#   change began at the new width (the window right after a change is not
+#   judged), and holds at least 32 GETs: a median of fewer is too noisy to
+#   compare with the tolerance (lognormal, sigma 0.5: an 11% error at 32);
+IO_WINDOW_GETS = 2.0
+IO_MIN_GETS = 32
+# - the CPU stage keeps up (waits for samples) while fewer than this share
+#   of the window's hand-offs found the fetch->decode queue full.  A stage
+#   that keeps up drains the queue, so hand-offs never wait; one that sets
+#   the pace lets it fill, and then nearly every hand-off waits.  (Takes
+#   that found the queue empty are no such signal: the process stage's pump
+#   collects samples between worker results, and on a TPU v5e host read
+#   0.17-0.38 empty takes per take while IO set the pace.)
+IO_FULL_SHARE = 0.5
+
+
+class _IOWidth:
+    """Sizes the IO gate from what the IO stage observes, where the width is
+    derived (``PipelineConfig.io_workers == 0``) and no autotuner owns it.
+
+    A latency-gradient concurrency limit (TCP Vegas; Netflix's gradient
+    limiter), by Little's law: the GETs that must be in flight are the rate
+    the CPU stage can absorb times the GET time.  The assembler calls
+    :meth:`update` as it loops, also while it waits for a batch that a
+    starved GET holds back; once per window (:data:`IO_WINDOW_GETS`,
+    :data:`IO_MIN_GETS`) of primary GETs it
+
+    * widens by :data:`IO_WIDEN`, up to ``ceiling`` (the samples the loader
+      keeps outstanding), while samples wait for an IO slot, the CPU stage
+      keeps up (:data:`IO_FULL_SHARE`) and GET latency stays within
+      :data:`IO_LATENCY_TOL`: the median GET of the lowest median seen, and
+      the oldest GET in flight of the window's 90th percentile GET;
+    * narrows by the same factor, never below the seed, when the median GET
+      exceeds that tolerance;
+    * when a GET in flight is starved (:data:`IO_STALL`), narrows one step
+      below the width at which that GET began (or the current width, if
+      lower), and widens no further than that for the rest of the epoch;
+    * otherwise holds.
+
+    The window after a change is not judged: its GETs began at the old
+    width.  On a local or in-memory store the CPU stage sets the pace and
+    the fetch->decode queue stays full, so the width stays at the seed.
+    Hedged duplicates are not read: the IO stage feeds it primary GET times
+    only."""
+
+    def __init__(self, io: _IOStage, decode_q: _BoundedQ, seed: int, tracer) -> None:
+        self.io = io
+        self.decode_q = decode_q
+        self.seed = seed
+        self.tracer = tracer
+        self.peak = seed
+        self.widened = 0
+        self.narrowed = 0
+        self._best = math.inf  # lowest short-window median GET seen
+        self._cap = math.inf  # set by a starved GET
+        # the current window began at another width (the first: while the
+        # stages started), so it is not judged
+        self._settling = True
+        self._next_t = 0.0  # the current window's earliest end
+        self._widths: Deque[Tuple[float, int]] = deque([(0.0, seed)], maxlen=64)
+        self._puts = 0
+        self._full_puts = 0
+        io.get_s = []
+
+    def update(self, ceiling: int) -> None:
+        gets = self.io.get_s
+        if len(gets) < IO_MIN_GETS:
+            return
+        now = time.monotonic()
+        if now < self._next_t:
+            return
+        self.io.get_s = []
+        median = float(np.median(gets))
+        self._next_t = now + IO_WINDOW_GETS * median
+        puts, full = self.decode_q.puts, self.decode_q.full_puts
+        d_puts, d_full = puts - self._puts, full - self._full_puts
+        self._puts, self._full_puts = puts, full
+        if self._settling:
+            self._settling = False
+            return
+        self._best = min(self._best, median)
+        limit = self.io.gate.limit
+        t0 = self.io.oldest_get_t0()
+        oldest = 0.0 if t0 is None else now - t0
+        p90 = float(np.percentile(gets, 90))
+        if oldest > IO_STALL * p90:
+            began = self._widths[0][1]
+            for t, w in self._widths:
+                if t > t0:
+                    break
+                began = w
+            new = min(limit, max(self.seed, int(began / IO_WIDEN)))
+            self._cap = min(self._cap, new)
+        elif median > IO_LATENCY_TOL * self._best:
+            new = max(self.seed, int(limit / IO_WIDEN))
+        elif (oldest <= IO_LATENCY_TOL * p90 and self.io.parked()
+              and d_full < IO_FULL_SHARE * d_puts):
+            new = max(limit, min(ceiling, self._cap,
+                                 max(limit + 1, math.ceil(limit * IO_WIDEN))))
+        else:
+            return
+        if new == limit:
+            return
+        new = self.io.resize(new)
+        self._widths.append((now, new))
+        self._settling = True
+        if new > limit:
+            self.peak = max(self.peak, new)
+            self.widened += 1
+            self.tracer.count(IO_WIDENED)
+        else:
+            self.narrowed += 1
+            self.tracer.count(IO_NARROWED)
+
+    def stats(self) -> Dict[str, int]:
+        return {"seed": self.seed, "limit": self.io.gate.limit, "peak": self.peak,
+                "widened": self.widened, "narrowed": self.narrowed}
 
 
 # ---------------------------------------------------------------------------
@@ -1250,8 +1457,10 @@ class _PipelineIter:
         self.strict = pipe.reorder == "strict"
         self.window = 1 if self.strict else max(1, pipe.reorder_window)
 
-        # stage sizing: 0 derives io_workers from the legacy loader's total
-        # fetch-thread count so pipeline-vs-legacy runs at equal concurrency
+        # stage sizing: 0 derives io_workers, seeded at the legacy loader's
+        # total fetch-thread count; without an autotuner _IOWidth then sizes
+        # the gate from observed GET latency (split datasets only: a
+        # monolithic fetch also decodes, so its width stays at the seed)
         io_workers = pipe.io_workers or max(1, cfg.num_workers * cfg.num_fetch_workers)
         cpu_workers = pipe.cpu_workers or 4
         queue_depth = max(1, pipe.stage_queue_depth)
@@ -1403,17 +1612,30 @@ class _PipelineIter:
             from repro.core.staging import HostBatchPool
 
             self._staging = HostBatchPool(depth=staging_n, tracer=self.tracer)
+        adaptive = pipe.io_workers == 0 and not at.enabled and self.split
+        if at.enabled:
+            io_cap = self._max_io_bound
+        elif adaptive:
+            # the executor's hard cap is the outstanding sample window
+            # (threads are still created lazily, up to the gate's limit)
+            io_cap = self.max_outstanding * cfg.batch_size
+        else:
+            io_cap = io_workers
         self.io = _IOStage(
             dataset,
             mode="asyncio" if cfg.impl == "asyncio" else "threaded",
             width=io_workers,
-            hard_cap=self._max_io_bound if at.enabled else io_workers,
+            hard_cap=io_cap,
             split=self.split,
             decode_q=self.decode_q,
             done_q=self.done_q,
             stop=self._stop,
             tracer=self.tracer,
             hedge=loader.hedge,
+        )
+        self._io_width = (
+            _IOWidth(self.io, self.decode_q, io_workers, self.tracer)
+            if adaptive else None
         )
         cpu_hard = self._max_cpu_bound if at.enabled else cpu_workers
         if not self.split:
@@ -1881,6 +2103,8 @@ class _PipelineIter:
 
         deadline = time.monotonic() + self.cfg.timeout_s
         while True:
+            if self._io_width is not None:
+                self._io_width.update(self.max_outstanding * (self._per_batch or 1))
             items = self._pop_ready()
             if items is not None:
                 self._pump()
@@ -1940,6 +2164,8 @@ class _PipelineIter:
             # entropy floor via the loader's entropy_fn
             "shuffle": self._shuffle.snapshot(),
         }
+        if self._io_width is not None:
+            out["io_width"] = self._io_width.stats()
         if self._budget:
             out["thread_budget"] = self._budget
         if self._staging is not None:

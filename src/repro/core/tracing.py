@@ -56,6 +56,10 @@ LOADER_WAIT = "loader_wait"
 # fetch->decode queue accepting the sample (the slot held, not fetching)
 IO_ADMIT = "io_admit"
 IO_HANDOFF = "io_handoff"
+# monotonic counters (repro.core.pipeline): steps of the derived IO width's
+# controller, one per widening and one per narrowing of the IO gate
+IO_WIDENED = "io_widened"
+IO_NARROWED = "io_narrowed"
 
 
 @dataclass

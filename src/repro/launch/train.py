@@ -153,7 +153,8 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
                          "stream) or window (first-N-ready composition)")
     ap.add_argument("--reorder-window", type=int, default=4)
     ap.add_argument("--io-workers", type=int, default=0,
-                    help="pipeline IO executor width (0 = workers*fetchers)")
+                    help="pipeline IO executor width (0 = adapt to observed "
+                         "GET latency, from workers*fetchers up)")
     ap.add_argument("--cpu-workers", type=int, default=0,
                     help="pipeline CPU executor width (0 = 4)")
     ap.add_argument("--cpu-executor", choices=["thread", "process"],
