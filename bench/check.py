@@ -1,17 +1,13 @@
-"""The comparison that decides ``correct``.
+"""The comparison that decides ``correct``: what every family's check shares.
 
 What the timed path produced in its first steps (the batches the step
 received, the losses it reported, its optimizer state after one step and its
-parameters after three) is set against the plain references in
-``bench/reference``. Each number is compared with a limit of its own, kept
-per cell in ``bench/limits/<cell>.json``:
+parameters after three) is set against the plain references of the cell's
+family (``bench/families/<family>.py``, whose ``compare`` adds the numbers of
+its own samples, such as the batches' rows). Each number is compared with a
+limit of its own, kept per cell in ``bench/limits/<cell>.json``. The numbers
+of a training step, from :func:`train_numbers`:
 
-* ``batch_mismatch`` -- rows (image or label) of the first three batches that
-  differ from the reference loader's. The step's input is the normalised
-  float32 batch; its uint8 pixels are recovered exactly by inverting the
-  normalisation and rounding. Exact: limit 0.
-* ``ingest_err`` -- the largest absolute gap between the ingest kernel's
-  output and a plain normalisation of the reference loader's pixels.
 * ``loss_gap`` -- the largest relative gap of a step's loss.
 * ``grad_gap`` -- the first gradient, as the optimizer got it (clipped),
   recovered from the first moment after one step (``mu / (1 - beta1)``): the
@@ -32,32 +28,14 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from bench.reference.model import MEAN, STD
-
 CHECKED_STEPS = 3
 
 
-def recover_u8(images_nchw: np.ndarray) -> np.ndarray:
-    """Normalised float (B, 3, H, W) -> the uint8 (B, H, W, 3) it came from."""
-    x = images_nchw.astype(np.float64).transpose(0, 2, 3, 1)
-    px = (x * np.asarray(STD) + np.asarray(MEAN)) * 255.0
-    return np.clip(np.rint(px), 0, 255).astype(np.uint8)
+def host_leaves(tree) -> List[np.ndarray]:
+    """A state's leaves as host arrays, in the tree's order."""
+    import jax
 
-
-def batch_mismatch(prog: Sequence, ref: Sequence) -> int:
-    """Rows that differ, over (images NCHW float, labels) against
-    (images NHWC uint8, labels)."""
-    if len(prog) != len(ref):
-        return sum(len(rlab) for _, rlab in ref)
-    bad = 0
-    for (img, lab), (rimg, rlab) in zip(prog, ref):
-        if img.shape[0] != rimg.shape[0]:
-            bad += len(rlab)
-            continue
-        u8 = recover_u8(img)
-        rows = (u8.reshape(len(u8), -1) != rimg.reshape(len(rimg), -1)).any(1)
-        bad += int((rows | (np.asarray(lab) != np.asarray(rlab))).sum())
-    return bad
+    return [np.asarray(x) for x in jax.tree.leaves(jax.device_get(tree))]
 
 
 def max_abs(a: np.ndarray, b: np.ndarray) -> float:
@@ -90,17 +68,14 @@ def gap_leaves(prog: Dict, ref: Dict, beta1: float):
     return leaf_gaps(g_prog, ref["g1"]), leaf_gaps(d_prog, d_ref, moving)
 
 
-def compare(prog: Dict, ref: Dict, beta1: float) -> Dict[str, float]:
-    """The numbers compared. ``prog``/``ref`` hold ``batches`` (as above),
-    ``losses``, ``p0`` and ``p3`` (parameter leaves before step 1 and after
-    step 3) and, for the program, ``mu1`` (first-moment leaves after step 1),
-    for the reference ``g1`` (its clipped first gradient), ``ingest`` (the
-    program's normalised batches) and ``normalized`` (the reference's)."""
+def train_numbers(prog: Dict, ref: Dict, beta1: float) -> Dict[str, float]:
+    """The training step's numbers. ``prog``/``ref`` hold ``losses``, ``p0``
+    and ``p3`` (parameter leaves before step 1 and after step 3) and, for the
+    program, ``mu1`` (first-moment leaves after step 1), for the reference
+    ``g1`` (its clipped first gradient)."""
     grad, update = gap_leaves(prog, ref, beta1)
     lp, lr = np.asarray(prog["losses"], np.float64), np.asarray(ref["losses"], np.float64)
-    out = {
-        "batch_mismatch": float(batch_mismatch(prog["batches"], ref["batches"])),
-        "ingest_err": max(max_abs(a, b) for a, b in zip(prog["ingest"], ref["normalized"])),
+    return {
         "loss_gap": float(np.max(np.abs(lp - lr) / np.abs(lr))) if len(lp) == len(lr)
         else float("inf"),
         "grad_gap": float(np.nanmax(grad)),
@@ -108,7 +83,6 @@ def compare(prog: Dict, ref: Dict, beta1: float) -> Dict[str, float]:
         "grad_gap_median": float(np.nanmedian(grad)),
         "update_gap_median": float(np.nanmedian(update)),
     }
-    return out
 
 
 def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:

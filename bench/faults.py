@@ -33,7 +33,7 @@ def wrap_step(fault: str, step):
         return unchanged
     if fault == "half":
         def half(state, batch):
-            n = batch["label"].shape[0] // 2
+            n = next(iter(batch.values())).shape[0] // 2
             return step(state, {k: v[:n] for k, v in batch.items()})
         return half
     raise ValueError(f"unknown fault {fault!r}")

@@ -1,11 +1,14 @@
 """One run of one cell: set-up, a measured window, then the check.
 
 The window drives the program's training path as ``launch/train.py`` wires
-it: ``make_loader`` over an ``ImageDataset`` whose store is the benchmark's
-(``bench/storage.py``), ``Trainer.fit`` with its device prefetch ring and the
-``make_ingest_fn`` epilogue, and the jitted ``make_resnet_train_step`` on a
-state from ``init_resnet_train_state``. It drives one chip; a cell on
-several chips needs the sharded delivery path added here first.
+it: ``make_loader`` over the dataset of the cell's family, whose store is
+the benchmark's (``bench/storage.py``), and ``Trainer.fit`` with its device
+prefetch ring on the family's jitted step. What depends on the family (the
+object pool, the dataset, the state, the step, a device epilogue, the
+references and the numbers compared) comes from
+``bench/families/<family>.py``; what every family shares is here. It drives
+one chip; a cell on several chips needs the sharded delivery path added
+here first.
 
 Set-up ends when the warm-up steps are done; the first of them compile, and
 the first three are the ones the check follows. The window then runs for
@@ -21,6 +24,7 @@ completed a fifth of an untraced one's steps.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import gc
 import json
 import multiprocessing
@@ -35,9 +39,6 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from bench import check, spec, storage
-from bench.flops import ingest_bytes_per_image, train_flops_per_image
-from bench.reference import loader as ref_loader
-from bench.reference import model as ref_model
 
 CHECKED = check.CHECKED_STEPS
 WARMUP = CHECKED + 1  # steps before the window opens
@@ -53,7 +54,9 @@ class NoChip(SystemExit):
 
 @dataclass
 class Run:
-    """What a per-layer metric's reader gets (``bench/metrics/<name>.py``)."""
+    """What a per-layer metric's reader gets (``bench/metrics/<name>.py``).
+    Where a family's sample is not an image, ``images_per_step`` and
+    ``flops_per_image`` count its samples."""
 
     chips: int
     images_per_step: int
@@ -64,6 +67,7 @@ class Run:
     config: Dict[str, Any]
     device: Any = None  # bench.trace.DeviceTrace of the window, or None
     peaks: Dict[str, float] = field(default_factory=dict)
+    family: Any = None  # the cell's bench/families/<family>.py
 
     @property
     def seconds(self) -> float:
@@ -74,10 +78,10 @@ class Run:
         return len(self.step_ends) * self.images_per_step / self.seconds
 
     def flops_per_image(self) -> float:
-        return train_flops_per_image(self.config)
+        return self.family.flops_per_sample(self.config)
 
     def ingest_bytes_per_image(self) -> float:
-        return ingest_bytes_per_image(self.config)
+        return self.family.ingest_bytes_per_sample(self.config)
 
 
 def profile_options():
@@ -104,19 +108,10 @@ def cpu_workers(rule, cores: int) -> int:
 
 def rehearsal(cell: spec.Cell) -> spec.Cell:
     """The same cell at tiny sizes, for a CPU rehearsal."""
-    config = dict(cell.config, resnet_blocks=[1, 1], resnet_width=8, image_size=32,
-                  batch_per_chip=8)
-    objects = dict(cell.traffic["objects"], pool=16, height=48, width=64, coarse=4)
+    config, objects = cell.family.rehearse(cell.config, cell.traffic["objects"])
     loader = dict(cell.traffic["loader"], cpu_workers=2)
     traffic = dict(cell.traffic, objects=objects, loader=loader)
-    return spec.Cell(cell.name, cell.chips, config, traffic, cell.limits,
-                     cell.end_to_end, cell.per_layer)
-
-
-def host_leaves(tree) -> List[np.ndarray]:
-    import jax
-
-    return [np.asarray(x) for x in jax.tree.leaves(jax.device_get(tree))]
+    return dataclasses.replace(cell, config=config, traffic=traffic)
 
 
 def shutdown_loader(loader) -> None:
@@ -155,8 +150,9 @@ def _probe_class():
         """Copies what the check needs in the first steps, opens the window
         after the warm-up and closes it once ``seconds`` have passed."""
 
-        def __init__(self, seconds: float, on_warm=None, mark=None):
+        def __init__(self, seconds: float, keep, on_warm=None, mark=None):
             self.seconds = seconds
+            self.keep = keep  # the host copy of a batch that the check reads
             self.on_warm = on_warm  # called before the last warm-up step
             self.mark = mark  # puts a marker on the profiler's clock
             self.batches: List[tuple] = []
@@ -172,8 +168,7 @@ def _probe_class():
                 self.batch_spec = jax.tree.map(
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding), batch)
             if trainer.global_step < CHECKED:
-                img, lab = jax.device_get((batch["image"], batch["label"]))
-                self.batches.append((np.asarray(img), np.asarray(lab)))
+                self.batches.append(self.keep(batch))
 
         def on_train_batch_end(self, trainer, metrics, idx):
             now = time.monotonic()
@@ -189,9 +184,9 @@ def _probe_class():
             if n <= CHECKED:
                 self.losses.append(float(metrics["loss"]))
             if n == 1:
-                self.mu1 = host_leaves(trainer.state["opt"]["mu"])
+                self.mu1 = check.host_leaves(trainer.state["opt"]["mu"])
             if n == CHECKED:
-                self.p3 = host_leaves(trainer.state["params"])
+                self.p3 = check.host_leaves(trainer.state["params"])
             if n == WARMUP - 1 and self.on_warm:
                 self.on_warm()
             if n == WARMUP:
@@ -202,83 +197,17 @@ def _probe_class():
     return Probe
 
 
-def run_reference(cell: spec.Cell, pool, seed: int) -> Dict:
-    """The reference loader's first batches and the reference step on them,
-    in float32 at ``highest`` precision, once the program's state is gone."""
-    cfg, tr = cell.config, cell.traffic
-    store = storage.PoolStore(pool, int(tr["keyspace"]), spec.derive(seed, "store"))
-    ref_batches = ref_loader.batches(
-        store, keyspace=int(tr["keyspace"]), batch=int(cfg["batch_per_chip"]), count=CHECKED,
-        sampler_seed=spec.derive(seed, "sampler"), aug_seed=spec.derive(seed, "aug"),
-        out=int(cfg["image_size"]), prefix=storage.PREFIX)
-    return ref_batches, reference_steps(cell, ref_batches, seed)
-
-
-def reference_steps(cell: spec.Cell, ref_batches, seed: int, dtype=None, rows=None,
-                    ingest_dtype=None, precision: str = "highest") -> Dict:
-    """Three reference steps from the seed's weights on ``ref_batches``.
-    ``dtype`` is the step's type (default float32) and ``precision`` its
-    matmul precision, ``ingest_dtype`` the normalisation's type (default
-    ``dtype``); with ``rows`` the step sees only the first rows of each
-    batch. They make the controls and the half-batch fault."""
+def warm_up(trainer, batch) -> None:
+    """Compile and run the step once on the family's batch of zeros (whose
+    making compiles a device epilogue), before the loader starts: a copy of
+    the state is donated, the real one is kept. So the loader's queues do
+    not fill while the first step compiles, and the window opens on a loader
+    that has run only as fast as the steps took its batches."""
     import jax
     import jax.numpy as jnp
 
-    cfg = cell.config
-    dtype = dtype or jnp.float32
-    ingest_dtype = ingest_dtype or dtype
-    blocks = tuple(cfg["resnet_blocks"])
-    with jax.default_matmul_precision(precision):
-        key = jax.random.PRNGKey(spec.derive(seed, "weights", 31))
-        init = jax.jit(ref_model.init_params, static_argnums=(1, 2, 3))
-        params = init(key, blocks, int(cfg["resnet_width"]), int(cfg["num_classes"]))
-        p0 = host_leaves(params)
-        mu = jax.tree.map(jnp.zeros_like, params)
-        nu = jax.tree.map(jnp.zeros_like, params)
-        step = ref_model.make_step(blocks, cfg["train"], dtype, rows)
-        norm = jax.jit(ref_model.normalize, static_argnums=(1,))
-        losses, normalized, g1 = [], [], None
-        for i, (u8, labels) in enumerate(ref_batches):
-            images = norm(jnp.asarray(u8), ingest_dtype)
-            normalized.append(np.asarray(images.astype(jnp.float32)))
-            params, mu, nu, loss, g = step(params, mu, nu, jnp.int32(i), images,
-                                           jnp.asarray(labels))
-            losses.append(float(loss))
-            if i == 0:
-                g1 = host_leaves(g)
-        return {"losses": losses, "normalized": normalized, "g1": g1, "p0": p0,
-                "p3": host_leaves(params)}
-
-
-def program_configs(cfg: Dict):
-    """The program's ModelConfig and TrainConfig for a configuration file."""
-    from repro.config import ModelConfig, TrainConfig
-
-    mcfg = ModelConfig(name=cfg["name"], family="resnet",
-                       resnet_blocks=tuple(cfg["resnet_blocks"]),
-                       resnet_width=int(cfg["resnet_width"]),
-                       num_classes=int(cfg["num_classes"]), image_size=int(cfg["image_size"]))
-    return mcfg, TrainConfig(**cfg["train"])
-
-
-def warm_up(trainer, ingest_fn, cfg: Dict, batch: int) -> None:
-    """Compile and run the step and the ingest once on zeros, before the
-    loader starts: a copy of the state is donated, the real one is kept. So
-    the loader's queues do not fill while the first step compiles, and the
-    window opens on a loader that has run only as fast as the steps took
-    its batches."""
-    import jax
-    import jax.numpy as jnp
-
-    side = int(cfg["image_size"])
-    label = jax.device_put(np.zeros((batch,), np.int32))
-    if ingest_fn is not None:
-        raw = jax.device_put(np.zeros((batch, side, side, 3), np.uint8))
-        images = ingest_fn({"image": raw, "label": label})["image"]
-    else:
-        images = jax.device_put(np.zeros((batch, 3, side, side), np.float32))
     state = jax.tree.map(lambda x: jnp.array(x, copy=True), trainer.state)
-    jax.block_until_ready(trainer.train_step(state, {"image": images, "label": label}))
+    jax.block_until_ready(trainer.train_step(state, batch))
 
 
 def step_temp_bytes(trainer, batch_spec) -> int:
@@ -301,6 +230,9 @@ def parse_args(argv):
     ap.add_argument("--fault", default="", help=argparse.SUPPRESS)
     ap.add_argument("--pool-dir", default=storage.POOL_DIR, help=argparse.SUPPRESS)
     ap.add_argument("--keep-trace", default="", help=argparse.SUPPRESS)
+    # a checkout of another benchmark (BENCHMARK.json and bench/configs,
+    # traffic, limits, families), for the harness's tests
+    ap.add_argument("--root", default=spec.ROOT, help=argparse.SUPPRESS)
     return ap.parse_args(argv)
 
 
@@ -326,7 +258,8 @@ def setup_jax(cell: spec.Cell, rehearse: bool):
 def main(argv=None, t_start: Optional[float] = None) -> int:
     t_start = time.monotonic() if t_start is None else t_start
     args = parse_args(argv)
-    cell = spec.resolve(args.workload, spec.load_benchmark())
+    root = os.path.abspath(args.root)
+    cell = spec.resolve(args.workload, spec.load_benchmark(root), root)
     if args.rehearse:
         cell = rehearsal(cell)
     try:
@@ -349,16 +282,13 @@ def run(cell: spec.Cell, args, devices, t_start: float, on_check=None) -> Option
     from repro.config import DeliverySpec, LoaderConfig, PipelineConfig
     from repro.core import make_loader
     from repro.core.tracing import NULL_TRACER, Tracer
-    from repro.data.dataset import ImageDataset
-    from repro.kernels.ingest_norm.ops import make_ingest_fn
-    from repro.train.steps import init_resnet_train_state, make_resnet_train_step
     from repro.train.trainer import Trainer
 
     from bench import faults
 
-    cfg, tr, ld = cell.config, cell.traffic, cell.traffic["loader"]
+    cfg, tr, ld, fam = cell.config, cell.traffic, cell.traffic["loader"], cell.family
     seed = args.seed
-    batch = int(cfg["batch_per_chip"])
+    batch = fam.samples_per_step(cfg)
     cores = len(os.sched_getaffinity(0))
     n_cpu = cpu_workers(ld["cpu_workers"], cores)
     print(f"cell {cell.name}: {len(devices)} chip {devices[0].device_kind}, batch {batch}, "
@@ -370,14 +300,11 @@ def run(cell: spec.Cell, args, devices, t_start: float, on_check=None) -> Option
         lambda event, secs, **kw: compiles.append(time.monotonic())
         if event == "/jax/core/compile/backend_compile_duration" else None)
 
-    pool = storage.load_pool(tr["objects"], args.pool_dir)
+    pool = fam.load_pool(tr["objects"], args.pool_dir)
     print(f"pool: {len(pool)} objects, mean {pool.mean_size():.0f} bytes", flush=True)
-    store = storage.build_store(tr, pool, spec.derive(seed, "store"))
+    store = storage.build_store(tr, pool, spec.derive(seed, "store"), fam.PREFIX)
     tracer = Tracer() if args.trace else NULL_TRACER
-    dataset_cls = faults.AlteredDataset if args.fault == "alter" else ImageDataset
-    dataset = dataset_cls(store, int(tr["keyspace"]), prefix=storage.PREFIX,
-                          out_size=int(cfg["image_size"]), seed=spec.derive(seed, "aug"),
-                          tracer=tracer, sim_decode_s_per_mb=0.0, epilogue=ld["epilogue"])
+    dataset = fam.dataset(cfg, tr, store, seed, tracer, args.fault)
     loader = make_loader(LoaderConfig(
         batch_size=batch, num_workers=int(ld["num_workers"]),
         num_fetch_workers=int(ld["num_fetch_workers"]),
@@ -386,12 +313,10 @@ def run(cell: spec.Cell, args, devices, t_start: float, on_check=None) -> Option
                                 staging_buffers=int(ld["staging_buffers"])),
         delivery=DeliverySpec.host(), seed=spec.derive(seed, "sampler")), dataset, tracer=tracer)
 
-    mcfg, tcfg = program_configs(cfg)
-    key = jax.random.PRNGKey(spec.derive(seed, "weights", 31))
-    state = jax.jit(lambda k: init_resnet_train_state(mcfg, tcfg, k))(key)
-    p0 = host_leaves(state["params"])
-    step_fn = faults.wrap_step(args.fault, make_resnet_train_step(mcfg, tcfg))
-    ingest_fn = make_ingest_fn() if ld["epilogue"] == "device" else None
+    state = fam.init_state(cfg, seed)
+    p0 = check.host_leaves(state["params"])
+    step_fn = faults.wrap_step(args.fault, fam.make_step(cfg))
+    options = fam.trainer_options(cfg, tr)
 
     logdir = tempfile.mkdtemp(prefix="bench-trace-") if args.trace else None
     marks: Dict[str, float] = {}
@@ -402,13 +327,13 @@ def run(cell: spec.Cell, args, devices, t_start: float, on_check=None) -> Option
             pass
 
     probe = _probe_class()(
-        args.seconds,
+        args.seconds, fam.keep,
         on_warm=(lambda: jax.profiler.start_trace(logdir, profiler_options=profile_options()))
         if args.trace else None,
         mark=mark if args.trace else None)
-    trainer = Trainer(step_fn, state, callbacks=[probe], tracer=tracer, ingest_fn=ingest_fn)
+    trainer = Trainer(step_fn, state, callbacks=[probe], tracer=tracer, **options)
     del state
-    warm_up(trainer, ingest_fn, cfg, batch)
+    warm_up(trainer, fam.warm_batch(cfg, options))
     try:
         trainer.fit(loader, epochs=1)
     except WindowClosed:
@@ -451,11 +376,10 @@ def run(cell: spec.Cell, args, devices, t_start: float, on_check=None) -> Option
     trainer.state = None
     del trainer, loader, dataset, store
     gc.collect()
-    ref_batches, ref = run_reference(cell, pool, seed)
-    prog = {"batches": probe.batches, "ingest": [b[0] for b in probe.batches],
-            "losses": probe.losses, "mu1": probe.mu1, "p0": p0, "p3": probe.p3}
-    ref["batches"] = ref_batches
-    numbers = check.compare(prog, ref, float(cfg["train"]["beta1"]))
+    ref = fam.reference(cfg, tr, pool, seed)
+    prog = {"batches": probe.batches, "losses": probe.losses, "mu1": probe.mu1, "p0": p0,
+            "p3": probe.p3}
+    numbers = fam.compare(prog, ref, cfg)
     log(f"numbers: {json.dumps(numbers)}")
     if on_check is not None:
         on_check(cell, seed, ref, prog, numbers)
@@ -508,7 +432,7 @@ def per_layer(cell, probe, window, tracer, stage_stats, logdir, marks, batch, ke
         breakdown = trace_mod.breakdown(dev_trace, host)
     run = Run(chips=1, images_per_step=batch, window=window, step_ends=probe.ends,
               spans=spans, stage_stats=stage_stats, config=cell.config, device=dev_trace,
-              peaks=peaks)
+              peaks=peaks, family=cell.family)
     metrics = {}
     for m in cell.per_layer:
         value = spec.reader(m["name"])(run)

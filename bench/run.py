@@ -1,6 +1,6 @@
 """Run one benchmark cell and print its result as the last line.
 
-    python3 bench/run.py --workload rn18.s3 --seed 7 --seconds 45 --trace 0
+    python3 bench/run.py --workload rn18.s3 --seed 7 --seconds 51 --trace 0
 
 Nothing but the standard library is imported at the top: the CPU stage's
 spawned workers import this file again, and they must never touch jax.

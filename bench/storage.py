@@ -1,15 +1,19 @@
-"""The traffic generator: a pool of image objects behind a keyspace, served
-from memory or through a model of remote object storage.
+"""The traffic generator: a pool of objects behind a keyspace, served from
+memory or through a model of remote object storage.
 
 Everything here is driven by a traffic file (``bench/traffic/<mix>.json``):
 
-* ``objects`` -- how the pool is made: RIMG records of ``height`` x
-  ``width`` x 3 uint8 pixels, a posterised smooth field with sparse noise,
-  zlib-compressed. ``noise_p`` sets how much of the image is noise and so
-  the stored size. The pool is made once per checkout from ``pool_seed`` and
-  kept under ``bench/.pool/``; later runs read it back.
-* ``keyspace`` -- how many keys the sampler walks. Key ``i`` is served by
-  pool object ``slot(i)``, a mapping drawn from the run's seed.
+* ``objects`` -- how the pool is made, which the cell's family reads
+  (``bench/families/<family>.py``, ``load_pool``). For images
+  (:func:`load_pool`): RIMG records of ``height`` x ``width`` x 3 uint8
+  pixels, a posterised smooth field with sparse noise, zlib-compressed.
+  ``noise_p`` sets how much of the image is noise and so the stored size.
+  That pool is made once per checkout from ``pool_seed`` and kept under
+  ``bench/.pool/``; later runs read it back. A family whose samples are
+  made quickly builds its pool in memory (:meth:`Pool.of`).
+* ``keyspace`` -- how many keys the sampler walks. Key ``i`` (after the
+  family's prefix) is served by pool object ``slot(i)``, a mapping drawn
+  from the run's seed.
 * ``storage`` -- ``{"kind": "local"}`` serves from the host's memory;
   ``{"kind": "s3", ...}`` adds, per GET, a connection-pool wait, a lognormal
   latency and a transfer time at ``min(bandwidth_per_conn, nic_bandwidth /
@@ -28,7 +32,7 @@ import struct
 import threading
 import time
 import zlib
-from typing import Dict
+from typing import Dict, Sequence
 
 import numpy as np
 
@@ -76,6 +80,12 @@ class Pool:
         self.blob = blob
         self.offsets = offsets
 
+    @classmethod
+    def of(cls, objects: Sequence[bytes]) -> "Pool":
+        offsets = np.zeros(len(objects) + 1, np.int64)
+        offsets[1:] = np.cumsum([len(o) for o in objects])
+        return cls(b"".join(objects), offsets)
+
     def __len__(self) -> int:
         return len(self.offsets) - 1
 
@@ -101,14 +111,13 @@ def load_pool(spec: Dict, pool_dir: str = POOL_DIR, workers: int = 0) -> Pool:
                 objs = mp.map(_make_object_star, jobs, chunksize=16)
         else:
             objs = [make_object(*j) for j in jobs]
-        offsets = np.zeros(n + 1, np.int64)
-        offsets[1:] = np.cumsum([len(o) for o in objs])
+        pool = Pool.of(objs)
         os.makedirs(pool_dir, exist_ok=True)
         tmp = f"{path}.{os.getpid()}.tmp"
         with open(tmp, "wb") as f:
             f.write(struct.pack("<Q", n))
-            f.write(offsets.tobytes())
-            f.write(b"".join(objs))
+            f.write(pool.offsets.tobytes())
+            f.write(pool.blob)
         os.replace(tmp, path)
     with open(path, "rb") as f:
         (n,) = struct.unpack("<Q", f.read(8))
@@ -183,9 +192,10 @@ class S3Model:
                     self._active -= 1
 
 
-def build_store(traffic: Dict, pool: Pool, seed: int):
-    """The store a traffic file describes, over ``pool``."""
-    base = PoolStore(pool, int(traffic["keyspace"]), seed)
+def build_store(traffic: Dict, pool: Pool, seed: int, prefix: str = PREFIX):
+    """The store a traffic file describes, over ``pool``, answering the keys
+    under ``prefix``."""
+    base = PoolStore(pool, int(traffic["keyspace"]), seed, prefix)
     st = dict(traffic["storage"])
     kind = st.pop("kind")
     if kind == "local":

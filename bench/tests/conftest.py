@@ -4,7 +4,10 @@ import sys
 
 import pytest
 
+from bench import spec
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
 
 
 @pytest.fixture(scope="session")

@@ -3,15 +3,16 @@ import json
 
 import pytest
 
-from bench.tests.conftest import bench_run
+from bench.tests.conftest import CELLS, bench_run
 
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("trace", ["0", "1"])
-def test_rehearsal_prints_a_well_formed_last_line(cpu_env, trace):
+def test_rehearsal_prints_a_well_formed_last_line(cpu_env, trace, cell):
     env, pool = cpu_env
-    p = bench_run(env, pool, "--workload", "rn18.s3", "--seed", str(2**31 + 12345),
+    p = bench_run(env, pool, "--workload", cell, "--seed", str(2**31 + 12345),
                   "--seconds", "2", "--trace", trace, "--rehearse")
     assert p.returncode == 0, p.stderr[-3000:]
     line = json.loads(p.stdout.strip().splitlines()[-1])

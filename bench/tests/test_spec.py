@@ -21,7 +21,8 @@ def _cells():
 @pytest.mark.parametrize("cell", _cells())
 def test_cell_resolves_to_its_files(cell):
     c = spec.resolve(cell, BENCH)
-    assert c.config["resnet_blocks"] and c.traffic["storage"]["kind"] in ("s3", "local")
+    assert c.family.samples_per_step(c.config) > 0
+    assert c.traffic["storage"]["kind"] in ("s3", "local")
     assert c.limits and all(v >= 0 for v in c.limits.values())
     names = {m["name"] for m in c.end_to_end}
     assert "setup_s" in names and len(names) >= 2

@@ -81,6 +81,6 @@ def test_mfu_of_the_recorded_step():
     cell = spec.resolve("rn18.s3", spec.load_benchmark())
     run = harness.Run(chips=1, images_per_step=256, window=t.window, step_ends=[], spans={},
                       stage_stats={}, config=cell.config, device=t,
-                      peaks={"bf16_flops_per_s": 197e12})
+                      peaks={"bf16_flops_per_s": 197e12}, family=cell.family)
     mfu = spec.reader("mfu")(run)
     assert 24 < mfu < 28
