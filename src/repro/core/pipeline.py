@@ -746,7 +746,7 @@ class _CPUStage:
 PROC_TASK_ATTEMPTS = 3
 
 
-def _cpu_proc_main(payload: bytes, conn, shm_spec=None) -> None:
+def _cpu_proc_main(payload: bytes, conn, shm_spec=None, ring_spec=None) -> None:
     """Spawn entry point for one CPU worker process.
 
     Runs ONLY ``decode_raw`` + ``augment_item`` on tasks received over the
@@ -763,10 +763,19 @@ def _cpu_proc_main(payload: bytes, conn, shm_spec=None) -> None:
     ``free`` returns slots the parent consumed, ``slab_reset`` reclaims
     everything at an epoch takeover, ``slab_cap`` is the autotuner's live
     pressure knob.  Anything that can't pack falls back to the pickle
-    ``done`` with the reason attached.  ``die`` is the test-only crash
-    injection hook (:meth:`_CPUProcessPool.inject_crash`).  A ``ready``
-    message, sent once the dataset is loaded, tells the parent it may send
-    tasks."""
+    ``done`` with the reason attached.
+
+    ``ring_spec`` = ``(name, slot_bytes, slots)`` attaches the parent-owned
+    input ring (same transport): a ``task_shm`` carries ``(slot, nbytes)``
+    in place of the raw bytes, which the worker copies out of the slot
+    before decoding and never reads again — the parent frees the slot when
+    it reads this task's answer, so no view on it may outlive the copy.  A
+    worker that cannot attach the ring exits (the parent would address
+    slots it cannot read), and the pump respawns it.
+
+    ``die`` is the test-only crash injection hook
+    (:meth:`_CPUProcessPool.inject_crash`).  A ``ready`` message, sent once
+    the dataset is loaded, tells the parent it may send tasks."""
     try:
         dataset = pickle.loads(payload)
     except BaseException as e:  # exotic: parent pre-validated pickling
@@ -787,6 +796,17 @@ def _cpu_proc_main(payload: bytes, conn, shm_spec=None) -> None:
             except OSError:
                 pass
             writer = None
+    ring = None
+    if ring_spec is not None:
+        try:
+            ring = shm_mod.RingReader(*ring_spec)
+        except BaseException as e:
+            try:
+                conn.send(("crash", f"worker could not attach input ring: {e!r}"))
+            except OSError:
+                pass
+            conn.close()
+            return
     try:
         conn.send(("ready",))
     except OSError:
@@ -832,8 +852,9 @@ def _cpu_proc_main(payload: bytes, conn, shm_spec=None) -> None:
                 die_on_task = msg[1]
                 continue
             os._exit(1)
-        _, sid, index, raw = msg
+        sid, index = msg[1], msg[2]
         try:
+            raw = ring.read(*msg[3:]) if tag == "task_shm" else msg[3]
             t0 = time.monotonic()
             decoded = dataset.decode_raw(raw, index)
             t1 = time.monotonic()
@@ -866,6 +887,8 @@ def _cpu_proc_main(payload: bytes, conn, shm_spec=None) -> None:
                 break
     if writer is not None:
         writer.close()
+    if ring is not None:
+        ring.close()
     conn.close()
 
 
@@ -882,11 +905,13 @@ class _ProcWorker:
     the pipe: during an epoch takeover the outgoing pump can still be
     mid-``send`` (pipe full behind a slow decode) when ``attach`` broadcasts
     the rebind — unsynchronized interleaved writes would corrupt the pickle
-    stream."""
+    stream.  ``recv_lock`` does the same for reads: two pumps reading one
+    pipe at once tear its messages."""
 
-    __slots__ = ("proc", "conn", "sids", "send_lock", "slab", "ready")
+    __slots__ = ("proc", "conn", "sids", "send_lock", "recv_lock", "slab",
+                 "ring", "ready")
 
-    def __init__(self, proc, conn, slab=None) -> None:
+    def __init__(self, proc, conn, slab=None, ring=None) -> None:
         self.proc = proc
         self.conn = conn
         self.sids: List[int] = []  # at most PROC_PREFILL_DEPTH entries
@@ -895,7 +920,10 @@ class _ProcWorker:
         # started workers decode everything after it
         self.ready = False
         self.send_lock = threading.Lock()
+        # held around every read of the pipe (see _ProcCPUStage._recv)
+        self.recv_lock = threading.Lock()
         self.slab: Optional[shm_mod.ParentSlab] = slab  # shm transport only
+        self.ring: Optional[shm_mod.InputRing] = ring  # shm transport only
 
     def send(self, msg: Tuple) -> None:
         with self.send_lock:
@@ -942,9 +970,11 @@ class _CPUProcessPool:
         self._lock = threading.Lock()
         self._closed = False
         # shm transport: (slot_bytes, slots) per worker slab, or None for
-        # the pickle pipe.  The parent creates/owns every slab; _slabs is a
-        # live list shared with the exit finalizer so segments allocated
-        # after respawns are still unlinked if the pool is never closed.
+        # the pickle pipe.  The parent creates/owns every slab and input
+        # ring (PROC_PREFILL_DEPTH slots of slot_bytes: one per task a
+        # worker may hold); _slabs is a live list of both, shared with the
+        # exit finalizer so segments allocated after respawns are still
+        # unlinked if the pool is never closed.
         # The shared _shutdown flag closes a shutdown race: the finalizer
         # runs BEFORE multiprocessing's own atexit terminates the daemon
         # workers, so the (daemon) pump thread may reap those corpses and
@@ -953,7 +983,7 @@ class _CPUProcessPool:
         # spawn once the flag is set.
         self.shm_spec = shm_spec
         self.slab_cap: Optional[int] = None  # live usable-slot bound
-        self._slabs: List[shm_mod.ParentSlab] = []
+        self._slabs: List[Any] = []  # ParentSlab and InputRing
         self._shutdown = threading.Event()
         self._finalizer = weakref.finalize(
             self, _finalize_pool, self._slabs, self._shutdown)
@@ -965,7 +995,16 @@ class _CPUProcessPool:
 
     def attach(self, stage: Any, payload: bytes) -> None:
         with self._lock:
+            # switch owners with every worker's read lock held: once this
+            # returns, the outgoing pump is mid-read on no pipe and reads
+            # none again (_ProcCPUStage._recv), so the new pump never races
+            # it for a message or loses one to it
+            locks = [w.recv_lock for w in self.workers]
+            for lock in locks:
+                lock.acquire()
             self.owner = stage
+            for lock in locks:
+                lock.release()
             rebind = payload != self.payload
             self.payload = payload
         if rebind:
@@ -977,21 +1016,22 @@ class _CPUProcessPool:
 
     def spawn_one(self) -> None:
         parent_conn, child_conn = self.ctx.Pipe()
-        slab = None
-        worker_spec = None
+        slab = ring = None
+        worker_spec = ring_spec = None
         if self.shm_spec is not None:
             slab = shm_mod.ParentSlab(*self.shm_spec)
-            self._slabs.append(slab)
-            worker_spec = slab.spec()
+            ring = shm_mod.InputRing(self.shm_spec[0], PROC_PREFILL_DEPTH)
+            self._slabs += (slab, ring)
+            worker_spec, ring_spec = slab.spec(), ring.spec()
         proc = self.ctx.Process(
             target=_cpu_proc_main,
-            args=(self.payload, child_conn, worker_spec),
+            args=(self.payload, child_conn, worker_spec, ring_spec),
             name=f"pipe-cpu-proc-{len(self.workers)}",
             daemon=True,
         )
         proc.start()
         child_conn.close()  # the child holds its own copy
-        w = _ProcWorker(proc, parent_conn, slab)
+        w = _ProcWorker(proc, parent_conn, slab, ring)
         if slab is not None and self.slab_cap is not None:
             # respawned workers must honour the tuned slab-pressure cap too
             try:
@@ -1018,6 +1058,8 @@ class _CPUProcessPool:
             # already-delivered views stay valid (parent owns the mapping);
             # the name is dropped now so nothing leaks past the pool
             w.slab.retire()
+        if w.ring is not None:
+            w.ring.close()  # its tasks are retried through other rings
 
     def reset_slabs(self) -> None:
         """Epoch takeover: every slot is reclaimed wholesale (a previous
@@ -1093,7 +1135,18 @@ class _ProcCPUStage:
     fresh work and retried on another worker up to ``PROC_TASK_ATTEMPTS``
     total attempts (raw bytes are kept parent-side until success, so a retry
     never refetches), the corpse is reaped and a replacement spawned — one
-    crash costs one sample at worst, never the epoch."""
+    crash costs one sample at worst, never the epoch.
+
+    With ``transport="shm"`` the pump hands each task's raw bytes over
+    through the worker's input ring (:class:`repro.core.shm.InputRing`)
+    and sends only ``("task_shm", sid, index, slot, nbytes)``; raw that is
+    not bytes or is larger than a slot goes in the ``task`` message as with
+    the pipe.  The pump takes the slot at dispatch and returns it when it
+    reads the worker's answer for that sid (``done``, ``done_shm`` or
+    ``err``; a stale answer from an abandoned stage's task too, so a
+    takeover never reuses a slot that task may still read), or with the
+    whole ring when it reaps the worker; a crash retry writes the
+    parent-side ``s.raw`` into another worker's ring."""
 
     def __init__(
         self,
@@ -1133,6 +1186,10 @@ class _ProcCPUStage:
         self.fallbacks: Dict[str, int] = {}
         self.spilled = 0  # shm samples copied out of a nearly full slab
         self.bytes_copied = 0
+        # the task direction: raw bytes sent through an input ring or in
+        # the task message, per task sent (a crash retry counts again)
+        self.raw_shm_samples = 0
+        self.raw_pipe_samples = 0
         pool.attach(self, payload)
         if pool.shm_spec is not None:
             pool.reset_slabs()
@@ -1173,9 +1230,12 @@ class _ProcCPUStage:
                     if w is None:
                         continue
                     try:
-                        self._resolve(w, w.conn.recv())
+                        msg = self._recv(w)
                     except (EOFError, OSError):
-                        pass  # worker died mid-send; next reap handles it
+                        continue  # worker died mid-send; next reap handles it
+                    if msg is None:
+                        return  # another stage owns the pool now
+                    self._resolve(w, msg)
             # fully idle case: _dispatch's bounded blocking get is the only
             # wait, so there is nothing further to sleep on here
 
@@ -1226,9 +1286,11 @@ class _ProcCPUStage:
             s = self._inflight[sid]
             w.sids.append(sid)
             try:
-                w.send(("task", sid, s.index, s.raw))
+                w.send(self._task(w, sid, s))
             except OSError:
                 w.sids.remove(sid)  # broken pipe = dead worker; reap + retry
+                if w.ring is not None:
+                    w.ring.release(sid)
                 self._retry_or_fail(
                     sid, RuntimeError(
                         f"cpu worker pid={w.proc.pid} lost sample {s.index} "
@@ -1236,12 +1298,25 @@ class _ProcCPUStage:
                     ),
                 )
 
+    def _task(self, w: _ProcWorker, sid: int, s: _Sample) -> Tuple:
+        """The task message for ``s``: its raw bytes through ``w``'s input
+        ring where they fit, else in the message itself."""
+        put = w.ring.put(sid, s.raw) if w.ring is not None else None
+        if put is None:
+            self.raw_pipe_samples += 1
+            return ("task", sid, s.index, s.raw)
+        self.raw_shm_samples += 1
+        return ("task_shm", sid, s.index) + put
+
     def _reap(self) -> None:
         dead = [w for w in list(self.pool.workers) if not w.proc.is_alive()]
         for w in dead:
             try:
                 while w.conn.poll():  # a result may have beaten the crash
-                    self._resolve(w, w.conn.recv())
+                    msg = self._recv(w)
+                    if msg is None:
+                        break
+                    self._resolve(w, msg)
             except (EOFError, OSError):
                 pass
             self.pool.crashes += 1
@@ -1271,6 +1346,13 @@ class _ProcCPUStage:
                 f"{self._boot_deaths} cpu workers died while starting; last "
                 f"worker diagnostic: {self.pool.last_error}"))))
 
+    def _recv(self, w: _ProcWorker) -> Optional[Tuple]:
+        """``w``'s next message, read only while this stage owns the pool
+        (None once it does not: :meth:`_CPUProcessPool.attach` switches
+        owners under every worker's ``recv_lock``)."""
+        with w.recv_lock:
+            return w.conn.recv() if self._owned() else None
+
     def _retry_or_fail(self, sid: int, exc: BaseException) -> None:
         s = self._inflight.get(sid)
         if s is None:
@@ -1299,6 +1381,8 @@ class _ProcCPUStage:
         sid = msg[1]
         if sid in w.sids:
             w.sids.remove(sid)
+        if w.ring is not None:
+            w.ring.release(sid)
         s = self._inflight.pop(sid, None)
         self._attempts.pop(sid, None)
         if s is None:
@@ -2193,6 +2277,8 @@ class _PipelineIter:
                 ),
                 "spilled": stage.spilled,
                 "bytes_copied": stage.bytes_copied,
+                "raw_shm_samples": stage.raw_shm_samples,
+                "raw_pipe_samples": stage.raw_pipe_samples,
             }
             if pool.shm_spec is not None:
                 slot_bytes, slots = pool.shm_spec

@@ -30,6 +30,27 @@ Samples that don't fit a slot (oversized) or aren't plain numeric arrays
 (ragged/object dtype), and moments when every slot is in flight, fall back
 to the pickle pipe per-sample — the fast path is an optimisation, never a
 correctness constraint.
+
+The other direction, parent -> worker, has an **input ring** per worker
+(:class:`InputRing`, read by :class:`RingReader`): the raw bytes of each
+task, so the pump copies a sample into shared memory instead of pickling
+it into the worker's socket, and the task message carries only ``(slot,
+nbytes)``.  Its rules differ from the slab's, as the parent both writes and
+frees:
+
+* **Allocation.**  The parent creates the ring beside the worker's slab and
+  owns its free-list; slots are taken only by the pump, at dispatch, and
+  held for the task's id.
+* **Return.**  A slot returns when the worker answers for that task id
+  (result, fallback or dataset error), whichever stage's pump reads the
+  answer, so an epoch takeover never frees a slot an abandoned stage's task
+  may still read.  The worker copies the bytes out on receipt and never
+  touches the slot again.  A dead worker's ring is closed whole when it is
+  reaped; the parent still holds every in-flight raw and retries it through
+  another worker's ring.
+* **Fallback.**  Raw that is not ``bytes``, is larger than a slot, or finds
+  no free slot goes through the pipe as before; the caller counts which way
+  each task went.
 """
 from __future__ import annotations
 
@@ -205,9 +226,84 @@ class ParentSlab:
             pass
 
 
-def close_slabs(slabs: List[ParentSlab]) -> None:
-    """weakref.finalize target for the process pool: unlink every segment at
-    interpreter exit even if the loader never closed the pool."""
+class InputRing:
+    """Parent-side input ring of one worker: creates/owns the segment and
+    its free-list, and copies each task's raw bytes into a slot held for
+    the task's id until :meth:`release`.  Locked, because during an epoch
+    takeover an outgoing and an incoming pump briefly share the worker."""
+
+    def __init__(self, slot_bytes: int, slots: int) -> None:
+        self.slot_bytes = int(slot_bytes)
+        self.slots = int(slots)
+        self.shm = shared_memory.SharedMemory(
+            create=True, size=max(self.slot_bytes * self.slots, 1))
+        self.name = self.shm.name
+        self._lock = threading.Lock()
+        self._free: List[int] = list(range(self.slots))
+        self._held: Dict[int, int] = {}  # task id -> slot
+        self._closed = False
+
+    def spec(self) -> Tuple[str, int, int]:
+        """(name, slot_bytes, slots) — what the worker needs to attach."""
+        return (self.name, self.slot_bytes, self.slots)
+
+    def put(self, sid: int, raw: Any) -> Optional[Tuple[int, int]]:
+        """Copy ``raw`` into a free slot held for task ``sid``: ``(slot,
+        nbytes)``, or None where it must go through the pipe (not bytes,
+        larger than a slot, no free slot, ring closed)."""
+        if type(raw) is not bytes or len(raw) > self.slot_bytes:
+            return None
+        with self._lock:
+            if self._closed or not self._free:
+                return None
+            slot = self._free.pop()
+            self._held[sid] = slot
+            base = slot * self.slot_bytes
+            self.shm.buf[base:base + len(raw)] = raw
+        return slot, len(raw)
+
+    def release(self, sid: int) -> None:
+        """The worker answered for ``sid``: its slot, if any, is free."""
+        with self._lock:
+            slot = self._held.pop(sid, None)
+            if slot is not None:
+                self._free.append(slot)
+
+    def close(self) -> None:
+        with self._lock:
+            if self._closed:
+                return
+            self._closed = True
+            self._held.clear()
+            try:
+                self.shm.unlink()
+            except FileNotFoundError:  # pragma: no cover - already gone
+                pass
+            self.shm.close()  # no view outlives put(): nothing can pin it
+
+
+class RingReader:
+    """Worker-side input ring: attaches to the parent's segment and copies
+    a task's raw bytes out of its slot (the parent frees the slot once the
+    worker answers, so nothing may keep a view on it)."""
+
+    def __init__(self, name: str, slot_bytes: int, slots: int) -> None:
+        # attach only; see SlabWriter on the resource tracker
+        self.shm = shared_memory.SharedMemory(name=name)
+        self.slot_bytes = int(slot_bytes)
+
+    def read(self, slot: int, nbytes: int) -> bytes:
+        base = slot * self.slot_bytes
+        return bytes(self.shm.buf[base:base + nbytes])
+
+    def close(self) -> None:
+        self.shm.close()
+
+
+def close_slabs(slabs: List[Any]) -> None:
+    """weakref.finalize target for the process pool: unlink every segment
+    (output slabs and input rings) at interpreter exit even if the loader
+    never closed the pool."""
     for slab in slabs:
         slab.close()
 

@@ -3,11 +3,13 @@
 Three layers:
 
 * unit tests over :mod:`repro.core.shm` (slot packing, generation guards,
-  fallback reasons, the live cap) and :mod:`repro.core.staging` (pooled
-  collate, release/GC recycling) — no processes involved;
+  fallback reasons, the live cap, the input ring) and
+  :mod:`repro.core.staging` (pooled collate, release/GC recycling) — no
+  processes involved;
 * the end-to-end bit-identity matrix ``transport={pipe,shm}`` against the
-  thread-stage reference, plus crash injection, oversized-sample fallback,
-  and resume-cursor equivalence over the real process pool;
+  thread-stage reference, plus crash injection, oversized-sample and
+  oversized-raw fallback, an epoch takeover over the input rings, and
+  resume-cursor equivalence over the real process pool;
 * a 4-device subprocess leg proving ``transport="shm"`` composes with
   sharded delivery (same pattern as test_delivery.py).
 """
@@ -15,16 +17,19 @@ import json
 import os
 import subprocess
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
 
 from repro.config import LoaderConfig, PipelineConfig
+from repro.core import pipeline as pipeline_mod
 from repro.core import shm as shm_mod
 from repro.core.loader import ConcurrentDataLoader
 from repro.core.staging import HostBatchPool
 from repro.core.tracing import BYTES_COPIED, Tracer
-from repro.data.dataset import ImageDataset, collate
+from repro.data.dataset import ImageDataset, collate, item_key
 from repro.data.imagenet_synth import SyntheticImageStore
 from repro.data.store import SimulatedS3Store
 
@@ -196,6 +201,78 @@ class TestSlab:
             parent.close()
 
 
+class TestInputRing:
+    def test_put_read_release(self):
+        ring = shm_mod.InputRing(64, 2)
+        reader = shm_mod.RingReader(*ring.spec())
+        try:
+            a = ring.put(1, b"first raw")
+            b = ring.put(2, bytes(range(64)))
+            assert a is not None and b is not None and a[0] != b[0]
+            assert reader.read(*a) == b"first raw"
+            assert reader.read(*b) == bytes(range(64))
+            # both slots held: the next task goes through the pipe
+            assert ring.put(3, b"x") is None
+            ring.release(1)
+            ring.release(1)  # a second answer for one task frees nothing
+            c = ring.put(3, b"third")
+            assert c[0] == a[0] and reader.read(*c) == b"third"
+            assert ring.put(4, b"y") is None
+        finally:
+            reader.close()
+            ring.close()
+
+    def test_raw_that_cannot_go_through_the_ring(self):
+        ring = shm_mod.InputRing(16, 2)
+        try:
+            assert ring.put(1, b"x" * 17) is None  # larger than a slot
+            assert ring.put(2, bytearray(b"abc")) is None  # not bytes
+            assert ring.put(3, b"x" * 16) == (1, 16)
+            ring.close()
+            assert ring.put(4, b"x") is None  # closed with its worker
+            ring.release(3)
+        finally:
+            ring.close()
+
+    def test_concurrent_pumps_never_share_a_slot(self):
+        # two pumps share a worker's ring during an epoch takeover: many
+        # threads with a short switch interval put, check that what the
+        # slot holds is still their own, and release
+        ring = shm_mod.InputRing(256, 2)
+        reader = shm_mod.RingReader(*ring.spec())
+        errors = []
+        deadline = time.monotonic() + 1.0
+
+        def pump(k):
+            sid = k
+            while time.monotonic() < deadline:
+                sid += 1000
+                raw = sid.to_bytes(8, "little") * 32
+                put = ring.put(sid, raw)
+                if put is None:
+                    continue
+                if reader.read(*put) != raw:
+                    errors.append(sid)
+                ring.release(sid)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=pump, args=(k,))
+                       for k in range(2 * (os.cpu_count() or 1))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=10)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+            reader.close()
+            ring.close()
+        assert errors == []
+        assert sorted(ring._free) == [0, 1] and ring._held == {}
+
+
 # --------------------------------------------------------------------------
 # unit: pinned staging pool
 # --------------------------------------------------------------------------
@@ -245,6 +322,10 @@ def test_transport_matrix_bit_identical(dataset):
         if transport == "shm":
             assert t["shm_samples"] > 0
             assert t["slab_slots"] == 8
+            # every raw object fits a slot: each task went through the ring
+            assert (t["raw_shm_samples"], t["raw_pipe_samples"]) == (N_ITEMS, 0)
+        else:
+            assert (t["raw_shm_samples"], t["raw_pipe_samples"]) == (0, N_ITEMS)
         if staging:
             assert stats["staging"]["leases"] >= len(got)
 
@@ -269,6 +350,144 @@ def test_oversized_samples_fall_back_to_pipe(dataset):
     t = stats["transport"]
     assert t["shm_samples"] == 0
     assert t["fallbacks"].get("oversize", 0) > 0
+
+
+def test_raw_larger_than_a_slot_goes_through_the_pipe(dataset):
+    ref, _ = epoch(dataset, pipe_cfg("pipe"))
+    sizes = sorted(len(dataset.get_raw(i)) for i in range(N_ITEMS))
+    slot = sizes[N_ITEMS // 2]  # about half the raw objects are larger
+    got, stats = epoch(dataset, pipe_cfg("shm", slot_bytes=slot))
+    assert digest(got) == digest(ref)
+    t = stats["transport"]
+    larger = sum(n > slot for n in sizes)
+    assert 0 < larger < N_ITEMS
+    assert t["raw_pipe_samples"] == larger
+    assert t["raw_shm_samples"] == N_ITEMS - larger
+
+
+def test_pipe_transport_sends_no_ring_tasks(dataset, monkeypatch):
+    tags = []
+    send = pipeline_mod._ProcWorker.send
+
+    def recording_send(self, msg):
+        tags.append(msg[0])
+        send(self, msg)
+
+    monkeypatch.setattr(pipeline_mod._ProcWorker, "send", recording_send)
+    dl = ConcurrentDataLoader(dataset, pipe_cfg("pipe"))
+    try:
+        list(dl)
+        assert all(w.ring is None for w in dl._cpu_pool.workers)
+    finally:
+        dl._cpu_pool.close()
+    assert tags.count("task") == N_ITEMS and "task_shm" not in tags
+    t = dl.stage_stats()["transport"]
+    assert (t["raw_shm_samples"], t["raw_pipe_samples"]) == (0, N_ITEMS)
+
+
+class _CountingStore:
+    """Counts GETs, so a test can tell a retry from a refetch."""
+
+    def __init__(self, store):
+        self.store = store
+        self.keys = []
+
+    def get(self, key):
+        self.keys.append(key)
+        return self.store.get(key)
+
+
+class _DiesOnceDecoding(ImageDataset):
+    """Kills its worker in the middle of decoding one sample, the first time
+    any worker decodes it (the marker file outlives the worker)."""
+
+    def __init__(self, store, n, marker, victim, **kw):
+        super().__init__(store, n, **kw)
+        self.marker = marker
+        self.victim = victim
+
+    def decode_raw(self, raw, index):
+        if index == self.victim and not os.path.exists(self.marker):
+            open(self.marker, "w").close()
+            os._exit(1)
+        return super().decode_raw(raw, index)
+
+
+def test_worker_killed_mid_decode_retries_from_the_ring(dataset, tmp_path):
+    ref, _ = epoch(dataset, pipe_cfg("pipe"))
+    store = _CountingStore(dataset.store)
+    ds = _DiesOnceDecoding(store, N_ITEMS, str(tmp_path / "died"), victim=13,
+                           out_size=24)
+    got, stats = epoch(ds, pipe_cfg("shm"))
+    assert os.path.exists(tmp_path / "died")
+    assert digest(got) == digest(ref)
+    assert stats["cpu_pool"]["crashes"] >= 1
+    requeued = stats["cpu_pool"]["requeued"]
+    assert requeued >= 1
+    # the dead worker's tasks were written into other workers' rings from
+    # the parent's copy of the raw bytes: no GET was made twice
+    t = stats["transport"]
+    assert (t["raw_shm_samples"], t["raw_pipe_samples"]) == (N_ITEMS + requeued, 0)
+    assert sorted(store.keys) == sorted(item_key(i) for i in range(N_ITEMS))
+
+
+def test_epoch_takeover_hands_no_ring_slot_out_twice(dataset, monkeypatch):
+    def takeover(executor):
+        # a dataset of its own: set_epoch would leak into the shared fixture
+        ds = ImageDataset(dataset.store, N_ITEMS, out_size=24)
+        dl = ConcurrentDataLoader(ds, pipe_cfg("shm", executor=executor))
+        first = iter(dl)
+        next(first)  # the abandoned stage keeps tasks in its workers' rings
+        dl.set_epoch(1)
+        rest = list(iter(dl))
+        first.shutdown()
+        return dl, rest
+
+    _, ref = takeover("thread")
+    doubles = []
+    put = shm_mod.InputRing.put
+
+    def checked_put(self, sid, raw):
+        got = put(self, sid, raw)
+        if got is not None:
+            with self._lock:
+                holders = [k for k, v in self._held.items() if v == got[0]]
+                if holders != [sid] or got[0] in self._free:
+                    doubles.append((got[0], holders))
+        return got
+
+    monkeypatch.setattr(shm_mod.InputRing, "put", checked_put)
+    dl, got = takeover("process")
+    try:
+        assert len(got) == N_ITEMS // BS and digest(got) == digest(ref)
+        assert doubles == []
+        def snapshot():
+            out = []
+            for w in dl._cpu_pool.workers:
+                with w.ring._lock:
+                    out.append((list(w.ring._free), dict(w.ring._held),
+                                set(w.sids), w.ring.slots))
+            return out
+
+        def settled(snap):
+            return all(set(held) <= sids for _, held, sids, _ in snap)
+
+        # let the workers answer whatever the abandoned stage left with them
+        # (a pump reading an answer drops its sid a moment before its slot)
+        deadline = time.monotonic() + 5.0
+        snap = snapshot()
+        while not settled(snap) and time.monotonic() < deadline:
+            time.sleep(0.05)
+            snap = snapshot()
+        for free, held, sids, slots in snap:
+            # no slot both free and held, none free twice, none lost, and
+            # only tasks the worker still owes an answer for hold a slot
+            assert len(set(free)) == len(free)
+            assert not set(free) & set(held.values())
+            assert len(free) + len(held) == slots
+            assert set(held) <= sids
+    finally:
+        dl._cpu_pool.close()
 
 
 def test_crash_mid_slab_write_retries_and_stream_survives(dataset):
