@@ -8,10 +8,10 @@ the other benches model GIL-RELEASING C codecs and so understate it.  This
 bench drives a genuinely GIL-holding synthetic decoder
 (:class:`repro.data.dataset.SpinDataset`: a pure-Python byte-crunch busy
 loop, deterministic output) through both CPU executors at an equal total
-thread budget and validates the escape plus its co-tuning story:
+thread width and validates the escape:
 
-* **process ≥ 1.5x thread at equal budget** — same io/cpu split, same
-  budget; only the executor kind changes.  The thread cell saturates near
+* **process ≥ 1.5x thread at equal width** — same io/cpu split; only the
+  executor kind changes.  The thread cell saturates near
   one core of decode; the spawn-process pool uses the machine.  On hosts
   that cannot physically run 1.5 cores of busy loop in parallel
   (cpu-shares-constrained CI containers), the demanded ratio is capped at
@@ -20,10 +20,6 @@ thread budget and validates the escape plus its co-tuning story:
 * **bit-identical strict stream** — ``reorder="strict"`` output is
   bit-identical across ``cpu_executor`` settings (and the legacy path):
   the GIL escape changes WHERE decode runs, never what it produces.
-* **budget co-tuning** — ``AutotuneConfig.thread_budget`` walks the io/cpu
-  *split* as one knob from the worst corner to within 90% of the best fixed
-  grid point, with io+cpu never exceeding the budget at any sampled step
-  (the fleet probes "where does the next thread help", it never inflates).
 """
 from __future__ import annotations
 
@@ -32,14 +28,13 @@ import statistics
 import time
 
 from benchmarks.common import Result, Scale, nest_loader_kwargs
-from repro.config import AutotuneConfig, LoaderConfig
+from repro.config import LoaderConfig
 from repro.core.loader import ConcurrentDataLoader
 from repro.data.dataset import SpinDataset
 
 NAME = "procpool"
 PAPER_REF = "Appendix A.4 (GIL ceiling) / beyond paper (process CPU stage)"
 
-BUDGET = 8  # total executor threads in every measured cell
 # the fixed split for the thread-vs-process pair: a NARROW IO stage on
 # purpose — with decode threads holding the GIL ~100% of the time, each IO
 # thread also waits whole switch-intervals for the interpreter between
@@ -57,7 +52,6 @@ ROUNDS = 3  # interleaved measured rounds per cell (after 1 warm-up)
 # claim round is ~15 s of wall-clock on a ~1.5-effective-core container, so
 # one background phase can flip a single measurement either way
 ATTEMPTS = 3
-GRID = (1, 2, 4, 6)  # fixed io widths for the co-tune reference grid
 
 
 def _dataset(scale: Scale, items: int, io_s: float = IO_S,
@@ -116,15 +110,11 @@ def _parallel_capacity(procs: int = 3, rounds: int = 4500) -> float:
 
 
 class _Cell:
-    def __init__(self, label: str, dataset, *, batch_size: int = BATCH,
-                 num_workers: int = 2, prefetch_factor: int = 4,
-                 **cfg) -> None:
+    def __init__(self, label: str, dataset, **cfg) -> None:
         self.label = label
         self.loader = ConcurrentDataLoader(
-            dataset, LoaderConfig(batch_size=batch_size, seed=7,
-                                  num_workers=num_workers,
-                                  prefetch_factor=prefetch_factor,
-                                  timeout_s=300.0,
+            dataset, LoaderConfig(batch_size=BATCH, seed=7, num_workers=2,
+                                  prefetch_factor=4, timeout_s=300.0,
                                   **nest_loader_kwargs(
                                       dict(cfg, pipeline=True))),
         )
@@ -152,7 +142,6 @@ class _Cell:
         stats = self.loader.stage_stats() or {}
         return {
             "cell": self.label,
-            "budget": BUDGET,
             "io_w": stats.get("io_workers"),
             "cpu_w": stats.get("cpu_workers"),
             "executor": stats.get("cpu_executor"),
@@ -207,74 +196,10 @@ def run(scale: Scale) -> Result:
         if escape >= need:
             break
 
-    # -- co-tune reference: fixed io/cpu splits under the budget -------------
-    grid = [
-        _Cell(f"grid {w}io+{BUDGET - w}cpu", _dataset(scale, items),
-              io_workers=w, cpu_workers=BUDGET - w, cpu_executor="process")
-        for w in GRID
-    ]
-    for cell in grid:
-        cell.run_epoch(measure=False)
-    for _ in range(ROUNDS - 1):
-        for cell in grid:
-            cell.run_epoch()
-    best_grid = max(c.tput for c in grid)
-
-    # -- budget co-tuning from the worst corner ------------------------------
-    # small batches + a shallow prefetch window keep the sampler alive for
-    # most of the epoch (the end-of-epoch drain is excluded from tuning);
-    # ~0.4s windows and a 20% dead-band ride out shared-CI burst noise
-    at = AutotuneConfig(enabled=True, thread_budget=BUDGET,
-                        interval_batches=4, min_window_s=0.4,
-                        warmup_windows=1, rel_improvement=0.2)
-    tuned = _Cell("co-tuned (from 1io)", _dataset(scale, 2 * items),
-                  batch_size=8, num_workers=1, prefetch_factor=2,
-                  io_workers=1, cpu_executor="process", autotune=at)
-    budget_ok = True
-    epochs = 6 if full else 5
-    for ep in range(epochs):
-        tuned.loader.set_epoch(ep)
-        tuned.epoch = ep + 1
-        it = iter(tuned.loader)
-        t0 = time.monotonic()
-        n = 0
-        for b in it:
-            n += len(b["label"])
-            # the co-tuner must never exceed the budget, at ANY step —
-            # sampled after every delivered batch
-            if it.io.gate.limit + it.cpu.width > BUDGET:
-                budget_ok = False
-        tuned.obs.append(n / (time.monotonic() - t0))
-    split_probed = any(e.knob == "io_cpu_split"
-                       for e in tuned.loader.autotuner.events
-                       if e.action == "probe")
-    # the co-tuner's LEARNED operating point vs the grid: a fresh bind()
-    # applies the controller's best settled state, which is what a longer
-    # run would keep operating at (the tuning epochs themselves are taxed
-    # by live probing — that exploration cost is bench_autotune's subject,
-    # not this claim's)
-    it = iter(tuned.loader)
-    learned_split = it.io.gate.limit
-    learned_kind = it.cpu_kind
-    it.shutdown()
-    evalc = _Cell(f"co-tuned eval {learned_split}io+{BUDGET - learned_split}cpu",
-                  _dataset(scale, items),
-                  io_workers=learned_split,
-                  cpu_workers=BUDGET - learned_split,
-                  cpu_executor=learned_kind)
-    evalc.run_epoch(measure=False)
-    for attempt in range(ATTEMPTS):
-        for _ in range(ROUNDS - 1):
-            evalc.run_epoch()
-        tuned_tput = evalc.tput
-        vs_grid = tuned_tput / best_grid
-        if vs_grid >= 0.9:
-            break
-
-    rows = [c.row() for c in pair + grid + [tuned, evalc]]
+    rows = [c.row() for c in pair]
     claims = [
         (f"process CPU stage escapes the GIL ceiling: >= {need:.2f}x the "
-         f"threaded stage at an equal {BUDGET}-thread budget on a "
+         f"threaded stage at an equal {IO_W + CPU_W}-thread width on a "
          f"GIL-holding decoder ({proc_tput:.0f} vs {thread_tput:.0f} img/s "
          f"= {escape:.2f}x; target is 1.5x, capped by this host's measured "
          f"{capacity:.2f}x 3-process parallel capacity)",
@@ -282,18 +207,10 @@ def run(scale: Scale) -> Result:
         ("reorder='strict' output is bit-identical across cpu_executor "
          "settings (thread == process == legacy)",
          ident_thread and ident_proc),
-        (f"budget co-tuner's learned split ({learned_split}io+"
-         f"{BUDGET - learned_split}cpu/{learned_kind}, walked from the worst "
-         f"corner as ONE knob) reaches >= 90% of the best fixed grid point "
-         f"({tuned_tput:.0f} vs {best_grid:.0f} img/s = {vs_grid:.2f}x)",
-         vs_grid >= 0.9 and split_probed),
-        (f"io+cpu widths never exceed thread_budget={BUDGET} at any sampled "
-         "step of the co-tuned run",
-         budget_ok),
     ]
     return Result(
         NAME, PAPER_REF, rows, claims,
         notes=f"SpinDataset: ~{SPIN_ROUNDS * 0.17:.0f} ms pure-Python decode "
               f"(holds the GIL), {IO_S * 1e3:.0f} ms simulated GET; "
-              f"budget {BUDGET} threads everywhere",
+              f"{IO_W}io+{CPU_W}cpu threads in both cells",
     )

@@ -25,7 +25,7 @@ BENCHES = [
     "bench_heatmap",       # Figs 10/11
     "bench_autotune",      # Figs 10/11, online (closed-loop knob control)
     "bench_pipeline",      # beyond paper: staged streaming pipeline (stages)
-    "bench_procpool",      # A.4 closed: process CPU stage + budget co-tune
+    "bench_procpool",      # A.4 closed: process CPU stage escapes the GIL
     "bench_multihost",     # beyond paper: multi-host coordination (coord)
     "bench_sharded",       # beyond paper: device-sharded batch delivery
     "bench_shm",           # beyond paper: zero-copy shm transport + ingest

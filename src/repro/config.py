@@ -414,19 +414,6 @@ class AutotuneConfig:
     # earlier pickle fallback; more slots = headroom for bursty decode.
     min_slab_slots: int = 4
     max_slab_slots: int = 512
-    # budget co-tuning (staged pipeline + split datasets only).  0 keeps the
-    # independent io_workers/cpu_workers knobs.  >0 fixes the TOTAL executor
-    # width at thread_budget and replaces those two knobs with one coupled
-    # "io_cpu_split" knob (value = IO width; CPU width = budget - value):
-    # instead of inflating both stages independently, the controller probes
-    # "where does the next thread help" under a fixed parallelism budget —
-    # the right question on a host whose cores are already spoken for.
-    thread_budget: int = 0
-    # with thread_budget set and a process-capable dataset (split path +
-    # picklable), also expose the CPU executor KIND (thread vs spawn-process)
-    # as a binary knob so the controller can buy the GIL escape only when the
-    # decode actually holds the GIL.
-    tune_cpu_executor: bool = True
     # -- objective ----------------------------------------------------------
     # "throughput" (default): score = windowed items/s (training loaders).
     # "latency": score = latency_target_s / windowed latency_quantile — the

@@ -1010,111 +1010,6 @@ def build_reorder_knob(
     )
 
 
-def budget_split_schedule(budget: int) -> Tuple[int, ...]:
-    """Coarse->fine ADDITIVE steps for the io/cpu split knob: start by moving
-    a quarter of the budget at a time, finish at single-thread granularity."""
-    steps = []
-    for s in (budget // 4, budget // 8, 1):
-        s = max(int(s), 1)
-        if not steps or s < steps[-1]:
-            steps.append(s)
-    return tuple(steps)
-
-
-def build_budget_knobs(
-    cfg: AutotuneConfig,
-    *,
-    budget: int,
-    lo_split: int,
-    hi_split: int,
-    get_split: Callable[[], int],
-    set_split: Callable[[int], int],
-    get_outstanding: Callable[[], int],
-    set_outstanding: Callable[[int], int],
-    get_queue: Callable[[], int],
-    set_queue: Callable[[int], int],
-    get_cpu_executor: Optional[Callable[[], int]] = None,
-    set_cpu_executor: Optional[Callable[[int], int]] = None,
-    hedge: Optional[Any] = None,
-    max_outstanding: Optional[int] = None,
-    max_queue: Optional[int] = None,
-    get_slab: Optional[Callable[[], int]] = None,
-    set_slab: Optional[Callable[[int], int]] = None,
-    max_slab: Optional[int] = None,
-    get_reorder: Optional[Callable[[], int]] = None,
-    set_reorder: Optional[Callable[[int], int]] = None,
-) -> List[Knob]:
-    """Knob set for a budget co-tuned ``_PipelineIter``
-    (``AutotuneConfig.thread_budget``): the independent ``io_workers`` /
-    ``cpu_workers`` knobs are REPLACED by one coupled ``io_cpu_split`` knob
-    whose value is the IO width (the owner derives the CPU width as
-    ``budget - value``), stepped additively coarse->fine — the controller
-    probes "where does the next thread help" under a fixed total instead of
-    inflating both widths.  When the owner can swap its CPU stage between
-    the thread pool and the spawn-process pool (split-path + picklable
-    dataset), the executor KIND rides along as a binary knob: a flip only
-    sticks when it actually buys windowed throughput (the GIL escape pays
-    for pure-Python decoders, the serialization tax loses for C ones).
-    Outstanding window, queue depth and hedging stay as in
-    :func:`build_pipeline_knobs` — they spend memory, not threads."""
-    knobs = [
-        Knob(
-            name="io_cpu_split",
-            get=get_split,
-            set=set_split,
-            lo=lo_split,
-            hi=hi_split,
-            scale="add",
-            step_schedule=budget_split_schedule(budget),
-        ),
-        Knob(
-            name="outstanding",
-            get=get_outstanding,
-            set=set_outstanding,
-            lo=cfg.min_outstanding,
-            hi=max(cfg.max_outstanding, max_outstanding or 0),
-        ),
-        Knob(
-            name="stage_queue",
-            get=get_queue,
-            set=set_queue,
-            lo=cfg.min_stage_queue,
-            hi=max(cfg.max_stage_queue, max_queue or 0),
-        ),
-    ]
-    if (
-        cfg.tune_cpu_executor
-        and get_cpu_executor is not None
-        and set_cpu_executor is not None
-    ):
-        knobs.append(
-            Knob("cpu_executor", get_cpu_executor, set_cpu_executor, 0, 1)
-        )
-    if get_slab is not None and set_slab is not None:
-        knobs.append(
-            Knob(
-                name="slab_slots",
-                get=get_slab,
-                set=set_slab,
-                lo=cfg.min_slab_slots,
-                hi=min(cfg.max_slab_slots, max_slab or cfg.max_slab_slots),
-            )
-        )
-    if cfg.tune_hedge and hedge is not None:
-        def _get_hedge() -> int:
-            return int(hedge.enabled)
-
-        def _set_hedge(v: int) -> int:
-            hedge.enabled = bool(v)
-            return int(hedge.enabled)
-
-        knobs.append(Knob("hedge", _get_hedge, _set_hedge, 0, 1))
-    if get_reorder is not None and set_reorder is not None:
-        knobs.append(build_reorder_knob(cfg, get_reorder=get_reorder,
-                                        set_reorder=set_reorder))
-    return knobs
-
-
 def build_serve_knobs(cfg: AutotuneConfig, path: Any) -> List[Knob]:
     """Knobs for a ``ReadPath``-shaped object (duck-typed so ``repro.core``
     never imports ``repro.serve``) under the latency objective: the hedge

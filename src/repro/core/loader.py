@@ -123,15 +123,6 @@ class ConcurrentDataLoader:
                     )
             if pipe.staging_buffers < 0:
                 raise ValueError("staging_buffers must be >= 0 (0 = off)")
-            at_ = cfg.autotune
-            if at_.enabled and at_.thread_budget:
-                floor = at_.min_fetch_workers + max(at_.min_cpu_workers, 1)
-                if at_.thread_budget < floor:
-                    raise ValueError(
-                        f"thread_budget={at_.thread_budget} cannot cover "
-                        f"min_fetch_workers + min_cpu_workers (= {floor}): "
-                        "the io/cpu split needs at least one thread per stage"
-                    )
         spec = cfg.delivery
         if spec.kind not in ("host", "sharded"):
             raise ValueError(

@@ -648,13 +648,7 @@ class _CPUStage:
     ``hard_cap`` threads exist; effective parallelism is the gate, so the
     autotuner resizes without thread churn.  The gate is acquired BEFORE
     pulling from the fetch->decode queue — a surplus thread waits empty-
-    handed rather than holding a sample hostage behind the gate.
-
-    ``active=False`` parks the stage (threads idle without pulling work):
-    the iterator flips it when the ``cpu_executor`` knob swaps the CPU stage
-    to the process pool — in-flight samples still finish here, new ones go
-    to whichever stage is active, and strict reorder is oblivious to which
-    executor produced a sample."""
+    handed rather than holding a sample hostage behind the gate."""
 
     def __init__(
         self,
@@ -674,7 +668,6 @@ class _CPUStage:
         self.tracer = tracer
         self.hard_cap = max(width, hard_cap)
         self.gate = AdjustableSemaphore(width)
-        self.active = True
         # threads are spawned lazily up to the CURRENT gate width (mirroring
         # ThreadPoolExecutor's lazy growth in the IO stage): a hard_cap of 32
         # must not cost 32 polling threads while the tuned width is 2
@@ -704,9 +697,6 @@ class _CPUStage:
 
     def _run(self) -> None:
         while not self.stop.is_set():
-            if not self.active:
-                time.sleep(0.05)
-                continue
             if not self.gate.acquire(timeout=0.1):
                 continue
             try:
@@ -1120,8 +1110,8 @@ class _CPUProcessPool:
 class _ProcCPUStage:
     """decode + augment in the spawn-process pool — same contract as
     :class:`_CPUStage` (pull from ``decode_q``, deliver to ``done_q``,
-    gate-bounded parallelism, live resize, ``active`` pause flag) with the
-    work itself outside the GIL.
+    gate-bounded parallelism, live resize) with the work itself outside the
+    GIL.
 
     One parent-side pump thread does everything: it claims samples from the
     fetch->decode queue under the :class:`AdjustableSemaphore` gate (a gate
@@ -1171,7 +1161,6 @@ class _ProcCPUStage:
         # `width` stays the stage's parallelism (worker count / knob value)
         self._width = max(1, width)
         self.gate = AdjustableSemaphore(PROC_PREFILL_DEPTH * self._width)
-        self.active = True
         self.requeued = 0  # samples retried after a worker crash
         self._boot_deaths = 0  # workers that died before their "ready"
         self._failed = False
@@ -1267,7 +1256,7 @@ class _ProcCPUStage:
             sid: Optional[int] = None
             if self._pending:
                 sid = self._pending.popleft()  # retry holds its permit already
-            elif self.active and self.gate.acquire(timeout=0):
+            elif self.gate.acquire(timeout=0):
                 any_busy = any(x.sids for x in self.pool.workers)
                 try:
                     # bounded blocking get when the whole stage is idle: the
@@ -1280,8 +1269,6 @@ class _ProcCPUStage:
                 self._inflight[sid] = s
                 self._attempts[sid] = 1
             else:
-                if not self.active and not self._pending:
-                    time.sleep(0.02)  # paused: don't spin on the gate
                 return
             s = self._inflight[sid]
             w.sids.append(sid)
@@ -1582,76 +1569,29 @@ class _PipelineIter:
                 self._max_outstanding_bound,
             )
 
-        # budget co-tuning (AutotuneConfig.thread_budget): io and cpu widths
-        # are one coupled knob under a fixed total, so normalize the static
-        # shape onto the budget here — the split value is the IO width and
-        # the CPU stage always gets the remainder
-        self._budget = (
-            at.thread_budget
-            if at.enabled and at.thread_budget > 0 and self.split
-            else 0
-        )
-        if at.enabled and at.thread_budget > 0 and not self.split:
-            # monolithic fallback: no CPU stage to trade against, but the
-            # budget is still a promise about total width — cap the IO knob
-            # at it rather than silently reverting to the unbounded ceiling
-            self._max_io_bound = min(self._max_io_bound, at.thread_budget)
-            io_workers = min(io_workers, at.thread_budget)
-        self._split_lo = self._split_hi = 0
-        if self._budget:
-            b = self._budget
-            self._split_lo = max(at.min_fetch_workers, b - self._max_cpu_bound, 1)
-            self._split_hi = max(self._split_lo, b - max(at.min_cpu_workers, 1))
-            seed = io_workers
-            if pipe.io_workers == 0 and "io_cpu_split" not in loader._tuned:
-                # cores-aware split seed: the CPU stage is compute-bound, so
-                # start it near the cores this process may actually use
-                # (cgroup quota aware) and give IO the budget's remainder —
-                # the co-tuner then begins near the optimum instead of at a
-                # constant derived from fetch-thread counts
-                from repro.core.utilization import available_cpu_count
-
-                seed = b - available_cpu_count()
-            io_workers = min(
-                max(loader._tuned.get("io_cpu_split", seed),
-                    self._split_lo),
-                self._split_hi,
-            )
-            cpu_workers = b - io_workers
-
-        # CPU executor kind: static config, overridden by the tuned value
-        # when the budget co-tuner flipped it in a previous epoch
+        # the CPU stage's kind, fixed for this iterator's life
         self.cpu_kind = pipe.cpu_executor if self.split else "thread"
-        if at.enabled and self.split and "cpu_executor" in loader._tuned:
-            self.cpu_kind = (
-                "process" if loader._tuned["cpu_executor"] else "thread"
-            )
         # the process stage ships a pickled dataset copy to each spawn
         # worker (decode/augment state only — see MapDataset's picklability
         # contract).  Pickle once, up front: a clear construction-time error
         # beats an opaque one from inside a worker.
         self._proc_payload: Optional[bytes] = None
-        if self.split and (
-            self.cpu_kind == "process"
-            or (self._budget and at.tune_cpu_executor)
-        ):
+        if self.cpu_kind == "process":
             try:
                 self._proc_payload = pickle.dumps(dataset)
             except Exception as e:
-                if self.cpu_kind == "process":
-                    raise ValueError(
-                        "cpu_executor='process' requires a picklable dataset "
-                        "(the process CPU stage ships a pickled copy to each "
-                        "spawn worker; drop store/tracer members on pickle — "
-                        "see MapDataset's picklability contract): "
-                        f"pickling failed with {e!r}"
-                    ) from e
-                self._proc_payload = None  # exec-kind knob just unavailable
+                raise ValueError(
+                    "cpu_executor='process' requires a picklable dataset "
+                    "(the process CPU stage ships a pickled copy to each "
+                    "spawn worker; drop store/tracer members on pickle — "
+                    "see MapDataset's picklability contract): "
+                    f"pickling failed with {e!r}"
+                ) from e
 
         # process-stage result transport: the zero-copy slab ring only means
-        # something when a process stage can exist (split + picklable);
-        # everything else keeps the pickle pipe (and the thread stage has no
-        # transport at all — items never leave the process)
+        # something for the process stage; everything else keeps the pickle
+        # pipe (and the thread stage has no transport at all — items never
+        # leave the process)
         self.transport = "pipe"
         self._shm_spec: Optional[Tuple[int, int]] = None
         if pipe.transport == "shm" and self._proc_payload is not None:
@@ -1727,14 +1667,7 @@ class _PipelineIter:
             # items, so the CPU stage processes nothing — don't spin up an
             # idle thread pool (much less a process pool) for it
             cpu_workers = cpu_hard = 1
-        self._cpu_hard = cpu_hard
-        self._cpu_width = cpu_workers
-        # both CPU stage kinds share decode_q/done_q and are created lazily;
-        # the inactive one (if ever created) is paused, so the cpu_executor
-        # knob can swap kinds mid-epoch without disturbing in-flight samples
-        self._thread_cpu: Optional[_CPUStage] = None
-        self._proc_cpu: Optional[_ProcCPUStage] = None
-        self.cpu = self._make_cpu_stage(self.cpu_kind)
+        self.cpu = self._make_cpu_stage(cpu_workers, cpu_hard)
 
         self._sampler_iter = iter(loader.sampler)
         self._exhausted = False
@@ -1773,7 +1706,6 @@ class _PipelineIter:
 
         if loader.autotuner is not None:
             from repro.core.autotune import (
-                build_budget_knobs,
                 build_pipeline_knobs,
                 make_weak_knob_callbacks,
             )
@@ -1799,110 +1731,70 @@ class _PipelineIter:
                     get_reorder=_wget(lambda it: it.window),
                     set_reorder=_wset(lambda it, n: it._set_reorder_window(n)),
                 )
-            if self._budget:
-                # budget co-tuning: ONE coupled io/cpu split knob (+ the
-                # executor kind when the dataset is process-capable) instead
-                # of two independent width knobs
-                proc_ok = self._proc_payload is not None
-                knobs = build_budget_knobs(
-                    at,
-                    budget=self._budget,
-                    lo_split=self._split_lo,
-                    hi_split=self._split_hi,
-                    get_split=_wget(lambda it: it.io.gate.limit),
-                    set_split=_wset(lambda it, n: it._set_split(n)),
-                    get_outstanding=_wget(lambda it: it.max_outstanding),
-                    set_outstanding=_wset(lambda it, n: it._set_outstanding(n)),
-                    get_queue=_wget(lambda it: it.decode_q.depth),
-                    set_queue=_wset(lambda it, n: it._set_stage_queue(n)),
-                    get_cpu_executor=(
-                        _wget(lambda it: int(it.cpu_kind == "process"))
-                        if proc_ok else None
-                    ),
-                    set_cpu_executor=(
-                        _wset(lambda it, n: it._set_cpu_executor(n))
-                        if proc_ok else None
-                    ),
-                    hedge=loader.hedge,
-                    max_outstanding=self._max_outstanding_bound,
-                    max_queue=self._max_queue_bound,
-                    **extra_kw,
-                )
-            else:
-                knobs = build_pipeline_knobs(
-                    at,
-                    get_io=_wget(lambda it: it.io.gate.limit),
-                    set_io=_wset(lambda it, n: it._set_io_workers(n)),
-                    get_cpu=_wget(lambda it: it.cpu.width),
-                    set_cpu=_wset(lambda it, n: it._set_cpu_workers(n)),
-                    get_outstanding=_wget(lambda it: it.max_outstanding),
-                    set_outstanding=_wset(lambda it, n: it._set_outstanding(n)),
-                    get_queue=_wget(lambda it: it.decode_q.depth),
-                    set_queue=_wset(lambda it, n: it._set_stage_queue(n)),
-                    hedge=loader.hedge,
-                    max_io=self._max_io_bound,
-                    max_cpu=self._max_cpu_bound,
-                    max_outstanding=self._max_outstanding_bound,
-                    max_queue=self._max_queue_bound,
-                    **extra_kw,
-                )
-                if not self.split:
-                    # nothing flows through the CPU stage or its queue —
-                    # inert knobs would waste the controller's probe windows
-                    knobs = [k for k in knobs
-                             if k.name not in ("cpu_workers", "stage_queue")]
+            knobs = build_pipeline_knobs(
+                at,
+                get_io=_wget(lambda it: it.io.gate.limit),
+                set_io=_wset(lambda it, n: it._set_io_workers(n)),
+                get_cpu=_wget(lambda it: it.cpu.width),
+                set_cpu=_wset(lambda it, n: it._set_cpu_workers(n)),
+                get_outstanding=_wget(lambda it: it.max_outstanding),
+                set_outstanding=_wset(lambda it, n: it._set_outstanding(n)),
+                get_queue=_wget(lambda it: it.decode_q.depth),
+                set_queue=_wset(lambda it, n: it._set_stage_queue(n)),
+                hedge=loader.hedge,
+                max_io=self._max_io_bound,
+                max_cpu=self._max_cpu_bound,
+                max_outstanding=self._max_outstanding_bound,
+                max_queue=self._max_queue_bound,
+                **extra_kw,
+            )
+            if not self.split:
+                # nothing flows through the CPU stage or its queue —
+                # inert knobs would waste the controller's probe windows
+                knobs = [k for k in knobs
+                         if k.name not in ("cpu_workers", "stage_queue")]
             loader.autotuner.bind(knobs)
             for knob in loader._cache_knobs:
                 loader.autotuner.attach_knob(knob)
 
         self._pump()
 
-    # -- CPU stage factory / executor swap -----------------------------------
-    def _make_cpu_stage(self, kind: str):
-        """Create (or reactivate) the CPU stage of the requested kind.  Both
-        kinds share decode_q/done_q/stop; the process kind attaches to the
-        loader-persistent :class:`_CPUProcessPool` (spawn cost is paid once,
-        not per epoch) and rebinding ships this epoch's dataset state."""
-        if kind == "process":
-            if self._proc_cpu is None:
-                pool = self.loader._cpu_pool
-                if (pool is None or pool.hard_cap < self._cpu_hard
-                        or pool._closed or pool.shm_spec != self._shm_spec):
-                    if pool is not None:
-                        pool.close()
-                    pool = _CPUProcessPool(self._proc_payload, self._cpu_hard,
-                                           shm_spec=self._shm_spec)
-                    self.loader._cpu_pool = pool
-                if self._shm_spec and self._slab_cap < self._shm_spec[1]:
-                    pool.set_slab_cap(self._slab_cap)
-                self._proc_cpu = _ProcCPUStage(
-                    self._proc_payload,
-                    pool=pool,
-                    width=self._cpu_width,
-                    hard_cap=self._cpu_hard,
-                    decode_q=self.decode_q,
-                    done_q=self.done_q,
-                    stop=self._stop,
-                    tracer=self.tracer,
-                )
-            else:
-                self._proc_cpu.active = True
-                self._proc_cpu.resize(self._cpu_width)
-            return self._proc_cpu
-        if self._thread_cpu is None:
-            self._thread_cpu = _CPUStage(
-                self.loader.dataset,
-                width=self._cpu_width,
-                hard_cap=self._cpu_hard,
+    # -- CPU stage factory ---------------------------------------------------
+    def _make_cpu_stage(self, width: int, hard_cap: int):
+        """Create this iterator's one CPU stage, of kind ``cpu_kind``.  The
+        process kind attaches to the loader-persistent
+        :class:`_CPUProcessPool` (spawn cost is paid once, not per epoch)
+        and rebinding ships this epoch's dataset state."""
+        if self.cpu_kind == "process":
+            pool = self.loader._cpu_pool
+            if (pool is None or pool.hard_cap < hard_cap
+                    or pool._closed or pool.shm_spec != self._shm_spec):
+                if pool is not None:
+                    pool.close()
+                pool = _CPUProcessPool(self._proc_payload, hard_cap,
+                                       shm_spec=self._shm_spec)
+                self.loader._cpu_pool = pool
+            if self._shm_spec and self._slab_cap < self._shm_spec[1]:
+                pool.set_slab_cap(self._slab_cap)
+            return _ProcCPUStage(
+                self._proc_payload,
+                pool=pool,
+                width=width,
+                hard_cap=hard_cap,
                 decode_q=self.decode_q,
                 done_q=self.done_q,
                 stop=self._stop,
                 tracer=self.tracer,
             )
-        else:
-            self._thread_cpu.active = True
-            self._thread_cpu.resize(self._cpu_width)
-        return self._thread_cpu
+        return _CPUStage(
+            self.loader.dataset,
+            width=width,
+            hard_cap=hard_cap,
+            decode_q=self.decode_q,
+            done_q=self.done_q,
+            stop=self._stop,
+            tracer=self.tracer,
+        )
 
     # -- autotuner control surfaces (applied between batches) ----------------
     def _set_io_workers(self, n: int) -> int:
@@ -1911,50 +1803,10 @@ class _PipelineIter:
         self.loader._tuned["io_workers"] = applied
         return applied
 
-    def _resize_cpu(self, n: int) -> int:
-        applied = self.cpu.resize(n)
-        self._cpu_width = applied
-        return applied
-
     def _set_cpu_workers(self, n: int) -> int:
         n = max(self.cfg.autotune.min_cpu_workers, int(n))
-        applied = self._resize_cpu(n)
+        applied = self.cpu.resize(n)
         self.loader._tuned["cpu_workers"] = applied
-        return applied
-
-    def _set_split(self, n: int) -> int:
-        """Apply one value of the coupled io/cpu split (budget mode): IO gets
-        ``n``, the CPU stage gets ``budget - n``.  The shrinking side is
-        resized first so the LIMITS never sum above the budget, even
-        transiently (surplus in-flight work drains through its gate)."""
-        n = max(self._split_lo, min(int(n), self._split_hi))
-        cpu = self._budget - n
-        if n >= self.io.gate.limit:
-            self._resize_cpu(cpu)
-            self.io.resize(n)
-        else:
-            self.io.resize(n)
-            self._resize_cpu(cpu)
-        self.loader._tuned["io_cpu_split"] = n
-        return n
-
-    def _set_cpu_executor(self, v: int) -> int:
-        """Swap the CPU stage kind live (binary budget-mode knob).  The old
-        stage is paused, not torn down: its in-flight samples finish into
-        the shared done_q (strict reorder is executor-oblivious), and a
-        revert two windows later reactivates it for free."""
-        want = "process" if int(v) >= 1 else "thread"
-        cur = int(self.cpu_kind == "process")
-        if want == self.cpu_kind:
-            return cur
-        if want == "process" and self._proc_payload is None:
-            return cur  # not process-capable: echo so the controller skips
-        old = self.cpu
-        self.cpu = self._make_cpu_stage(want)
-        old.active = False
-        self.cpu_kind = want
-        applied = int(want == "process")
-        self.loader._tuned["cpu_executor"] = applied
         return applied
 
     def _set_outstanding(self, n: int) -> int:
@@ -1980,9 +1832,8 @@ class _PipelineIter:
         hi = self._shm_spec[1] if self._shm_spec else 1
         n = max(at.min_slab_slots, min(int(n), hi))
         self._slab_cap = n
-        stage = self._proc_cpu
-        if stage is not None:
-            stage.pool.set_slab_cap(n)
+        if isinstance(self.cpu, _ProcCPUStage):
+            self.cpu.pool.set_slab_cap(n)
         self.loader._tuned["slab_slots"] = n
         return n
 
@@ -2068,8 +1919,8 @@ class _PipelineIter:
             return item
         out = item.detach()
         nbytes = shm_mod.item_nbytes(out)
-        self._proc_cpu.spilled += 1
-        self._proc_cpu.bytes_copied += nbytes
+        self.cpu.spilled += 1
+        self.cpu.bytes_copied += nbytes
         self.tracer.count(BYTES_COPIED, nbytes)
         return out
 
@@ -2250,21 +2101,19 @@ class _PipelineIter:
         }
         if self._io_width is not None:
             out["io_width"] = self._io_width.stats()
-        if self._budget:
-            out["thread_budget"] = self._budget
         if self._staging is not None:
             out["staging"] = self._staging.stats()
-        if self._proc_cpu is not None:
-            pool = self._proc_cpu.pool
+        stage = self.cpu
+        if isinstance(stage, _ProcCPUStage):
+            pool = stage.pool
             out["cpu_pool"] = {
                 "workers": len(pool.workers),
                 "crashes": pool.crashes,
                 "respawns": pool.respawns,
-                "requeued": self._proc_cpu.requeued,
+                "requeued": stage.requeued,
             }
             if pool.last_error:
                 out["cpu_pool"]["last_error"] = pool.last_error
-            stage = self._proc_cpu
             samples = stage.shm_samples + stage.pipe_samples
             tr: Dict[str, Any] = {
                 "kind": self.transport,
@@ -2322,12 +2171,9 @@ class _PipelineIter:
         if self._assembler is not None:
             self._assembler.close()
         self.io.close()
-        # join every CPU stage ever created this epoch (an executor-kind
-        # flip leaves the paused one alive); the process POOL persists on
-        # the loader — only the pump thread belongs to this iterator
-        for stage in (self._thread_cpu, self._proc_cpu):
-            if stage is not None:
-                stage.join()
+        # the process POOL persists on the loader — only the pump thread
+        # belongs to this iterator
+        self.cpu.join()
 
     def __del__(self) -> None:  # pragma: no cover - best effort
         try:

@@ -9,54 +9,11 @@ by the fraction of it covered by ``run_training_batch`` spans.
 """
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
 from repro.core.tracing import RUN_TRAINING_BATCH, Span, Tracer, union_duration
-
-
-def _parse_cgroup_quota() -> Optional[int]:
-    """Cores granted by the container's cpu controller, or None when
-    unlimited / not containerized.  Checks cgroup v2 (``cpu.max``:
-    ``"<quota_us> <period_us>"`` or ``"max <period_us>"``) then v1
-    (``cfs_quota_us`` / ``cfs_period_us``, quota -1 = unlimited)."""
-    try:
-        with open("/sys/fs/cgroup/cpu.max", "r") as f:
-            quota_s, period_s = f.read().split()[:2]
-        if quota_s != "max":
-            return max(1, int(int(quota_s) / int(period_s)))
-        return None
-    except (OSError, ValueError, IndexError):
-        pass
-    try:
-        with open("/sys/fs/cgroup/cpu/cpu.cfs_quota_us", "r") as f:
-            quota = int(f.read())
-        with open("/sys/fs/cgroup/cpu/cpu.cfs_period_us", "r") as f:
-            period = int(f.read())
-        if quota > 0 and period > 0:
-            return max(1, quota // period)
-    except (OSError, ValueError):
-        pass
-    return None
-
-
-def available_cpu_count() -> int:
-    """Cores this process may actually use: the minimum of the cgroup cpu
-    quota (containers are routinely granted far fewer cores than the node
-    has) and the scheduling affinity mask.  This is the cores-aware seed
-    for the pipeline's io/cpu thread split — ``os.cpu_count()`` alone
-    overstates it badly inside a quota'd container."""
-    counts = [c for c in (_parse_cgroup_quota(),) if c]
-    proc_count = getattr(os, "process_cpu_count", None)
-    if proc_count is not None:  # Python >= 3.13: affinity-aware
-        counts.append(proc_count() or 1)
-    elif hasattr(os, "sched_getaffinity"):
-        counts.append(len(os.sched_getaffinity(0)) or 1)
-    else:  # pragma: no cover - non-Linux fallback
-        counts.append(os.cpu_count() or 1)
-    return max(1, min(counts))
 
 
 @dataclass

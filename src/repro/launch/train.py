@@ -108,8 +108,7 @@ def build_loader(cfg, args, tracer, mesh=None):
             ),
             delivery=delivery,
             autotune=AutotuneConfig(
-                enabled=args.autotune or args.thread_budget > 0,
-                thread_budget=args.thread_budget,
+                enabled=args.autotune,
             ),
             seed=args.seed,
         ),
@@ -187,10 +186,6 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--autotune", action="store_true",
                     help="online knob control (closed-loop io/cpu/queue/"
                          "outstanding tuning)")
-    ap.add_argument("--thread-budget", type=int, default=0,
-                    help="co-tune the pipeline io/cpu split (and executor "
-                         "kind) as ONE knob under this fixed total width; "
-                         "implies --autotune (0 = independent knobs)")
     ap.add_argument("--optimizer", default="adamw")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--microbatches", type=int, default=1)

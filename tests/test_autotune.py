@@ -531,15 +531,12 @@ def test_autotune_never_caps_static_config(dataset):
     it.shutdown()
 
 
-def test_build_budget_knobs_shape_and_schedule():
-    from repro.core.autotune import (
-        budget_split_schedule,
-        build_budget_knobs,
-        make_weak_knob_callbacks,
-    )
+@pytest.mark.parametrize("with_reorder", [False, True])
+def test_build_pipeline_knobs_slab_bound_and_reorder(with_reorder):
+    from repro.core.autotune import build_pipeline_knobs
 
-    cfg = AutotuneConfig(enabled=True, thread_budget=16)
-    state = {"split": 4, "out": 8, "q": 64, "exec": 0}
+    cfg = AutotuneConfig(enabled=True, max_slab_slots=512)
+    state = {"io": 8, "cpu": 2, "out": 4, "q": 64, "slab": 8, "win": 2}
 
     def setter(key):
         def s(n):
@@ -547,38 +544,31 @@ def test_build_budget_knobs_shape_and_schedule():
             return int(n)
         return s
 
-    knobs = build_budget_knobs(
-        cfg, budget=16, lo_split=1, hi_split=15,
-        get_split=lambda: state["split"], set_split=setter("split"),
+    extra = (dict(get_reorder=lambda: state["win"], set_reorder=setter("win"))
+             if with_reorder else {})
+    knobs = build_pipeline_knobs(
+        cfg,
+        get_io=lambda: state["io"], set_io=setter("io"),
+        get_cpu=lambda: state["cpu"], set_cpu=setter("cpu"),
         get_outstanding=lambda: state["out"], set_outstanding=setter("out"),
         get_queue=lambda: state["q"], set_queue=setter("q"),
-        get_cpu_executor=lambda: state["exec"], set_cpu_executor=setter("exec"),
+        get_slab=lambda: state["slab"], set_slab=setter("slab"), max_slab=8,
+        **extra,
     )
     by_name = {k.name: k for k in knobs}
-    # the independent width knobs are replaced by the coupled split knob
-    assert set(by_name) == {"io_cpu_split", "outstanding", "stage_queue",
-                            "cpu_executor"}
-    split = by_name["io_cpu_split"]
-    assert (split.lo, split.hi) == (1, 15)
-    assert split.scale == "add"  # a +-budget/4 jump, not a x2 jump
-    assert split.step_schedule == budget_split_schedule(16) == (4, 2, 1)
-    assert by_name["cpu_executor"].is_binary
-    # tune_cpu_executor=False / no setter -> no executor knob
-    assert "cpu_executor" not in {
-        k.name for k in build_budget_knobs(
-            AutotuneConfig(enabled=True, thread_budget=16,
-                           tune_cpu_executor=False),
-            budget=16, lo_split=1, hi_split=15,
-            get_split=lambda: 4, set_split=setter("split"),
-            get_outstanding=lambda: 8, set_outstanding=setter("out"),
-            get_queue=lambda: 64, set_queue=setter("q"),
-            get_cpu_executor=lambda: 0, set_cpu_executor=setter("exec"),
-        )
-    }
-    assert budget_split_schedule(8) == (2, 1)
-    assert budget_split_schedule(3) == (1,)
+    assert set(by_name) == (
+        {"io_workers", "cpu_workers", "outstanding", "stage_queue",
+         "slab_slots"} | ({"reorder_window"} if with_reorder else set())
+    )
+    # the slab knob never reaches past the slots the pool allocated
+    slab = by_name["slab_slots"]
+    assert (slab.lo, slab.hi) == (cfg.min_slab_slots, 8)
 
-    # weak callbacks: once the owner dies, get reports 0 / set echoes
+
+def test_weak_knob_callbacks_release_their_owner():
+    """Once the owner dies, get reports 0 and set echoes its argument."""
+    from repro.core.autotune import make_weak_knob_callbacks
+
     class Owner:
         value = 5
 

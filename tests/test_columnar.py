@@ -175,12 +175,13 @@ def test_corrupted_footer_detected():
 @settings(max_examples=40, deadline=None)
 def test_truncation_property(cut, seed):
     rng = np.random.default_rng(seed)
-    blob = pack_shard(ragged_rows(rng, 6), random_meta(rng, 6), rows_per_chunk=2)
+    packed_rows = ragged_rows(rng, 6)
+    packed_meta = random_meta(rng, 6)
+    blob = pack_shard(packed_rows, packed_meta, rows_per_chunk=2)
     cut = min(cut, len(blob))
     if cut == 0:
         rows, meta = unpack_shard(blob)
-        assert len(rows) == 6 and meta["label"] == random_meta(
-            np.random.default_rng(seed), 6)["label"]
+        assert len(rows) == 6 and meta["label"] == packed_meta["label"]
     else:
         # any strict prefix must be rejected, never silently misread
         with pytest.raises(TruncatedShard):

@@ -24,6 +24,7 @@ SEED = 4  # num_workers 2 x num_fetch_workers 2
 CEILING = 2 * 4 * BS  # num_workers x prefetch_factor batches outstanding
 LATENCY_S = 0.03
 CONNS = 6
+DECODE_S = 0.003  # fixed decode cost where the CPU stage must set the pace
 
 
 @pytest.fixture(scope="module")
@@ -41,12 +42,24 @@ def _s3(base, conns=256, sigma=0.0):
                             bandwidth_per_conn=1e9, max_connections=conns)
 
 
-def _loader(store, io_workers=0, cpu_executor="thread", **kw):
+class _FixedDecodeCost(ImageDataset):
+    """Decode costs a fixed :data:`DECODE_S` more (a GIL-releasing sleep, as
+    a C codec), so the CPU stage is far slower than in-memory GETs and the
+    fetch->decode queue stays full whatever the host's load."""
+
+    def decode_raw(self, raw, index):
+        time.sleep(DECODE_S)
+        return super().decode_raw(raw, index)
+
+
+def _loader(store, io_workers=0, cpu_executor="thread", dataset=ImageDataset,
+            stage_queue_depth=64, **kw):
     pipe = PipelineConfig(enabled=True, io_workers=io_workers, cpu_workers=2,
-                          cpu_executor=cpu_executor)
+                          cpu_executor=cpu_executor,
+                          stage_queue_depth=stage_queue_depth)
     cfg = LoaderConfig(batch_size=BS, num_workers=2, num_fetch_workers=2,
                        prefetch_factor=4, seed=1, pipeline=pipe, **kw)
-    ds = ImageDataset(store, N_ITEMS, out_size=8, augment=False)
+    ds = dataset(store, N_ITEMS, out_size=8, augment=False)
     return ConcurrentDataLoader(ds, cfg, tracer=Tracer())
 
 
@@ -62,7 +75,11 @@ def _epoch(loader):
 @pytest.mark.parametrize("store", ["s3", "memory", "s3_pool"])
 def test_derived_width_follows_what_the_io_stage_observes(memory_store, store):
     if store == "memory":
-        loader = _loader(memory_store)
+        # a shallow queue fills within the first (unjudged) window, and the
+        # decode cost keeps it full: every judged window finds most
+        # hand-offs blocked, so the width has no reason to move
+        loader = _loader(memory_store, dataset=_FixedDecodeCost,
+                         stage_queue_depth=4)
     else:
         loader = _loader(_s3(memory_store, conns=CONNS if store == "s3_pool" else 256))
     limits, seconds = _epoch(loader)

@@ -8,7 +8,7 @@ of it within each aligned window of batches.
 import numpy as np
 import pytest
 
-from repro.config import AutotuneConfig, LoaderConfig
+from repro.config import AutotuneConfig, LoaderConfig, PipelineConfig
 from repro.core.loader import ConcurrentDataLoader
 from repro.core.tracing import (
     STAGE_AUGMENT,
@@ -427,79 +427,98 @@ def test_bad_cpu_executor_rejected(dataset):
         ConcurrentDataLoader(dataset, LoaderConfig(cpu_executor="fork"))
 
 
-# -- budget co-tuning --------------------------------------------------------
+# -- autotuned CPU stage -----------------------------------------------------
 
 
-def test_thread_budget_below_floor_rejected(dataset):
-    at = AutotuneConfig(enabled=True, thread_budget=1)
-    with pytest.raises(ValueError, match="thread_budget"):
-        ConcurrentDataLoader(
-            dataset, LoaderConfig(pipeline=True, autotune=at))
+@pytest.mark.parametrize("hedge", [False, True])
+def test_autotuned_process_stage_binds_independent_knobs_and_resizes(hedge):
+    """One CPU stage, chosen at construction: the controller tunes its width
+    with the independent knobs, and a mid-epoch resize keeps the strict
+    stream bit-identical to autotune off."""
+    from repro.core.pipeline import PROC_PREFILL_DEPTH, _ProcCPUStage
 
-
-def test_thread_budget_co_tunes_split_within_budget():
-    BUDGET = 6
-    ds = SpinDataset(96, item_bytes=256, spin_rounds=2, io_s=0.002)
-    at = AutotuneConfig(enabled=True, thread_budget=BUDGET,
-                        interval_batches=1, min_window_s=0.0,
-                        warmup_windows=0, tune_cpu_executor=False)
-    cfg = LoaderConfig(batch_size=4, num_workers=1, prefetch_factor=2,
-                       io_workers=1, pipeline=True, seed=5, timeout_s=60,
-                       autotune=at)
-    dl = ConcurrentDataLoader(ds, cfg)
-    for ep in range(3):
-        dl.set_epoch(ep)
-        it = iter(dl)
-        for _ in it:
-            # the invariant the co-tuner exists for: at EVERY step the two
-            # stage widths stay inside the budget
-            assert it.io.gate.limit + it.cpu.width <= BUDGET
-    knob_names = {k.name for k in dl.autotuner.knobs}
-    assert "io_cpu_split" in knob_names
-    # the independent width knobs are REPLACED, not supplemented
-    assert not knob_names & {"io_workers", "cpu_workers"}
-    probed = {e.knob for e in dl.autotuner.events if e.action == "probe"}
-    assert "io_cpu_split" in probed
-    assert "io_cpu_split" in dl._tuned
-
-
-def test_thread_budget_caps_io_for_unsplittable_dataset():
-    """A monolithic dataset has no CPU stage to trade against, but
-    thread_budget is still a promise about total width: the IO knob must be
-    capped at the budget, not silently unbounded."""
-    BUDGET = 3
-    ds = SyntheticTokenDataset(64, 16, 100)
-    at = AutotuneConfig(enabled=True, thread_budget=BUDGET,
-                        interval_batches=1, min_window_s=0.0,
-                        warmup_windows=0)
-    cfg = LoaderConfig(batch_size=4, num_workers=1, prefetch_factor=2,
-                       pipeline=True, seed=5, timeout_s=60, autotune=at)
-    dl = ConcurrentDataLoader(ds, cfg)
-    for ep in range(2):
-        dl.set_epoch(ep)
-        it = iter(dl)
-        assert not it.split and it._budget == 0
-        for _ in it:
-            assert it.io.gate.limit <= BUDGET
-
-
-def test_cpu_executor_knob_swaps_stage_mid_epoch():
     ds = SpinDataset(64, item_bytes=256, spin_rounds=2)
-    at = AutotuneConfig(enabled=True, thread_budget=4)
-    cfg = LoaderConfig(batch_size=8, num_workers=1, prefetch_factor=2,
-                       seed=9, timeout_s=60, pipeline=True, autotune=at)
+    # a long interval keeps the controller's own probes out of the test:
+    # every knob move below is the test's
+    at = AutotuneConfig(enabled=True, interval_batches=10_000,
+                        tune_hedge=hedge)
+    pipe = PipelineConfig(enabled=True, cpu_workers=1, cpu_executor="process",
+                          transport="shm", slab_slots=8)
+    cfg = LoaderConfig(batch_size=8, num_workers=1, prefetch_factor=2, seed=9,
+                       timeout_s=60, pipeline=pipe, autotune=at,
+                       hedge_requests=hedge)
     dl = ConcurrentDataLoader(ds, cfg)
     it = iter(dl)
+    assert isinstance(it.cpu, _ProcCPUStage)
+    knobs = {k.name: k for k in dl.autotuner.knobs}
+    assert set(knobs) == (
+        {"io_workers", "cpu_workers", "outstanding", "stage_queue",
+         "slab_slots"} | ({"hedge"} if hedge else set())
+    )
     batches = [next(it)]
-    knob = next(k for k in dl.autotuner.knobs if k.name == "cpu_executor")
-    assert knob.get() == 0
-    assert knob.set(1) == 1  # thread -> process: spawns/attaches the pool
-    assert it.cpu_kind == "process"
+    assert knobs["cpu_workers"].set(3) == 3
+    assert it.cpu.width == 3
+    assert it.cpu.gate.limit == PROC_PREFILL_DEPTH * 3
+    assert len(it.cpu.pool.workers) == 3
     batches.append(next(it))
-    assert knob.set(0) == 0  # and back; in-flight samples are unaffected
-    assert it.cpu_kind == "thread"
+    assert knobs["cpu_workers"].set(2) == 2
+    assert it.stage_stats()["cpu_workers"] == 2
     batches += list(it)
     got = [(b["x"].tolist(), b["label"].tolist()) for b in batches]
-    ref = spin_digest(ds, batch_size=8, num_workers=1, prefetch_factor=2,
-                      seed=9)
-    assert got == ref  # strict reorder is executor-oblivious
+    assert got == spin_digest(ds, batch_size=8, num_workers=1,
+                              prefetch_factor=2, seed=9)
+    assert dl.stage_stats()["cpu_executor"] == "process"
+    assert dl._tuned["cpu_workers"] == 2
+
+
+def test_autotuned_unsplittable_dataset_binds_no_cpu_knobs():
+    """A monolithic dataset sends nothing through the CPU stage or its
+    queue, so their knobs would only waste the controller's probes."""
+    ds = SyntheticTokenDataset(64, 16, 100)
+    at = AutotuneConfig(enabled=True, interval_batches=1, min_window_s=0.0,
+                        warmup_windows=0)
+    cfg = LoaderConfig(batch_size=4, num_workers=1, prefetch_factor=2,
+                       shuffle=False, pipeline=True, seed=5, timeout_s=60,
+                       autotune=at)
+    dl = ConcurrentDataLoader(ds, cfg)
+    it = iter(dl)
+    assert not it.split
+    knob_names = {k.name for k in dl.autotuner.knobs}
+    assert knob_names == {"io_workers", "outstanding"}
+    got = list(it)
+    ref = list(ConcurrentDataLoader(
+        ds, LoaderConfig(batch_size=4, num_workers=1, shuffle=False)))
+    assert len(got) == len(ref)
+    assert all((a["tokens"] == b["tokens"]).all() for a, b in zip(ref, got))
+
+
+class _RefusesPickle(SpinDataset):
+    """A split dataset that counts and refuses every pickling attempt."""
+
+    attempts = 0
+
+    def __getstate__(self):
+        type(self).attempts += 1
+        raise TypeError("this dataset does not pickle")
+
+
+@pytest.mark.parametrize("executor", ["thread", "process"])
+def test_only_the_process_stage_pickles_the_dataset(executor):
+    ds = _RefusesPickle(32, item_bytes=256, spin_rounds=1)
+    _RefusesPickle.attempts = 0
+    kw = dict(batch_size=8, num_workers=1, prefetch_factor=2, seed=4)
+    dl = ConcurrentDataLoader(ds, LoaderConfig(
+        timeout_s=60, pipeline=PipelineConfig(enabled=True,
+                                              cpu_executor=executor),
+        **kw))
+    if executor == "process":
+        with pytest.raises(ValueError, match="picklable"):
+            iter(dl)
+        assert _RefusesPickle.attempts == 1
+        return
+    it = iter(dl)
+    assert it.cpu_kind == "thread" and it._proc_payload is None
+    got = [(b["x"].tolist(), b["label"].tolist()) for b in it]
+    assert got == spin_digest(SpinDataset(32, item_bytes=256, spin_rounds=1),
+                              **kw)
+    assert _RefusesPickle.attempts == 0
